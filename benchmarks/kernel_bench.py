@@ -26,7 +26,6 @@ from repro.kernels.prox.kernel import (
     fused_update_pallas,
     fused_update_sweep_pallas,
     prox_pallas,
-    sweep_layout,
     sweep_params_table,
 )
 from repro.kernels.prox.ref import fused_update_ref, prox_l1_ref
@@ -68,12 +67,11 @@ def fused_sweep_section(quick: bool = True) -> dict:
     tf = _time(fused, x, y, nu, params, iters=iters)
     tu = _time(unfused, x, y, nu, params, iters=iters)
 
-    lay = sweep_layout(d)
-    traffic = fused_sweep_traffic(d, S, C, padded=lay.padded)
+    traffic = fused_sweep_traffic(d, S, C)
     roof = fused_sweep_roofline(traffic, tf.blocked_us * 1e-6)
     return {
         "grid": "sweep-major fused update (S, C, param tiles)",
-        "S": S, "C": C, "d": d, "padded_per_client": lay.padded,
+        "S": S, "C": C, "d": d,
         "backend": jax.default_backend(),
         "fused_us_blocked": round(tf.blocked_us, 1),
         "fused_us_dispatch": round(tf.dispatch_us, 1),

@@ -46,8 +46,8 @@ def roofline_terms(
     return terms
 
 
-def fused_sweep_traffic(d: int, S: int, C: int, *, dtype_bytes: int = 4,
-                        padded: int | None = None) -> dict:
+def fused_sweep_traffic(d: int, S: int, C: int, *,
+                        dtype_bytes: int = 4) -> dict:
     """HBM-traffic / FLOP model for the sweep-major fused DEPOSITUM update.
 
     The fused Pallas kernel reads {x, y, nu} and writes {x', nu'} exactly
@@ -59,11 +59,10 @@ def fused_sweep_traffic(d: int, S: int, C: int, *, dtype_bytes: int = 4,
     threshold select chain) = 9; the kernel is memory-bound by two orders
     of magnitude, so the ratio of sweeps IS the predicted speedup.
 
-    ``padded`` (elements per client after lane/sublane padding, e.g.
-    ``sweep_layout(d).padded``) gives the bytes the kernel actually moves;
-    defaults to the logical ``d``.
+    The kernel views each leaf in place (no padding copy), so it moves
+    exactly the logical ``d`` elements per client.
     """
-    n = float(S) * C * (padded if padded is not None else d)
+    n = float(S) * C * d
     fused_bytes = 5.0 * n * dtype_bytes
     unfused_bytes = 8.0 * n * dtype_bytes
     flops = 9.0 * n

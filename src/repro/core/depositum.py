@@ -236,13 +236,14 @@ def init(params: PyTree, n_clients: int, stacked: bool = False,
         x = jax.tree_util.tree_map(
             lambda v: jnp.concatenate(
                 [v, jnp.zeros((pad,) + v.shape[1:], v.dtype)]), x)
-    z = _zeros_like(x)
     spec = (compress if isinstance(compress, CompressionSpec)
             else active_compression(compress) if compress is not None
             else None)
     comm = ({"x": comm_memory(x), "y": comm_memory(x)}
             if spec is not None and spec.kind != "none" else ())
-    return DepositumState(x=x, y=z, nu=z, mu=z, g=z,
+    # one buffer per variable: a round program may donate the whole state
+    return DepositumState(x=x, y=_zeros_like(x), nu=_zeros_like(x),
+                          mu=_zeros_like(x), g=_zeros_like(x),
                           t=jnp.zeros((), jnp.int32), comm=comm)
 
 
@@ -260,6 +261,7 @@ def step(
     is_comm_step: jnp.ndarray | bool | None = None,
     hyper: Hyper | None = None,
     active_mask: jnp.ndarray | None = None,
+    client_shards: Any = None,
 ) -> tuple[DepositumState, Any]:
     """One DEPOSITUM iteration for all clients.
 
@@ -291,11 +293,17 @@ def step(
     None and the mixer is a ``cohort`` schedule, this round's mask is
     derived from the schedule's sampler (:func:`schedule_round_mask`);
     round loops compute it once and pass it to every local step.
+
+    ``client_shards`` is the devices' split of the client dim that the
+    fused kernels follow (the shard_map backend's mixers carry it as
+    ``mixer.client_shards``, the default); None on one device.
     """
     is_cohort_mixer = False
     comm_spec = None       # active CompressionSpec of this round's schedule
     qmix = None            # how the compressed increment q communicates
     key_x = key_y = None
+    shards = (client_shards if client_shards is not None
+              else getattr(mixer, "client_shards", None))
     if isinstance(mixer, (MixSchedule, ScheduleMixer)):
         is_cohort_mixer = getattr(mixer, "schedule", mixer).kind == "cohort"
         r = state.t // config.comm_period
@@ -368,7 +376,7 @@ def step(
         hp_vec = hyper_param_vec(hp)
         x_half, nu_next = fused_local_update(
             state.x, state.y, state.nu, hp_vec, kernel_mask,
-            kind=config.prox_name)
+            kind=config.prox_name, shards=shards)
         mu_next = state.mu
     else:
         with annotate("local_step"):
@@ -423,7 +431,7 @@ def step(
         from repro.kernels.prox.ops import fused_tracking
 
         y_half, g_next = fused_tracking(
-            state.y, g_next, state.g, hp_vec, kernel_mask)
+            state.y, g_next, state.g, hp_vec, kernel_mask, shards=shards)
     else:
         with annotate("local_step"):
             y_half = tm(
@@ -517,11 +525,13 @@ def local_then_comm_round(
         config.validate(hyper)  # once per round; no-op for traced values
     if active_mask is None:
         active_mask = schedule_round_mask(mixer, state.t // T0)
+    shards = getattr(mixer, "client_shards", None)
 
     def local_body(carry, batch):
         new_state, aux = step(
             carry, batch, grad_fn, config, identity_mixer,
             is_comm_step=False, hyper=hyper, active_mask=active_mask,
+            client_shards=shards,
         )
         return new_state, aux
 

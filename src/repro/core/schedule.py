@@ -500,11 +500,16 @@ class ScheduleMixer:
     the CHOCO exchange in ``depositum.step`` puts fewer bytes on the wire
     than the dense ``fn``.  None means "mix q with ``fn``" (stacked-vmap
     simulation, or an unpackable schedule kind).
+
+    ``client_shards`` — set by the shard_map backend — is its split of the
+    client dim over devices, which the fused update kernels follow
+    (:class:`~repro.kernels.prox.kernel.ClientShards`); None on one device.
     """
 
     fn: Callable[[PyTree, Any], PyTree]
     schedule: MixSchedule
     wire_fn: Optional[Callable[[PyTree, Any], PyTree]] = None
+    client_shards: Any = None
 
     def __call__(self, tree: PyTree, r) -> PyTree:
         return self.fn(tree, r)

@@ -1,3 +1,20 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels of the round program (prox / fused update) and flash
+attention.  Both families run under Mosaic on a TPU and in the Pallas
+interpreter on the CPU, and refuse any other backend."""
+import jax
+
+
+def interpret_mode() -> bool:
+    """Mosaic on a TPU, the Pallas interpreter on the CPU, nothing else.
+
+    Read at trace time by every ``pallas_call`` of this package, so a test
+    that compiles for a described TPU from a CPU process steers it here.
+    """
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run on 'tpu' (Mosaic) or 'cpu' (interpret mode), "
+        f"not on {backend!r}")

@@ -21,14 +21,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro import kernels
 
 NEG_INF = -1e30
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
-
-
-def _should_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -119,32 +118,14 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
-            pl.pallas_call if False else _scratch((block_q,), jnp.float32),
-            _scratch((block_q,), jnp.float32),
-            _scratch((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        interpret=_should_interpret(),
-        compiler_params=_compiler_params(),
+        interpret=kernels.interpret_mode(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
     )(q, k, v)
     return out
 
-
-def _scratch(shape, dtype):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # pragma: no cover
-        return pl.MemorySpace.ANY(shape, dtype)  # type: ignore
-
-
-def _compiler_params():
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        )
-    except Exception:  # pragma: no cover
-        return None

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.obs.trace import annotate
 from repro.kernels.prox.kernel import (
+    ClientShards,
     fused_tracking_sweep_pallas,
     fused_update_pallas,
     fused_update_sweep_pallas,
@@ -67,18 +68,19 @@ def fused_update_tree(x_tree, y_tree, nu_tree, *, kind: str, lam,
 # ---------------------------------------------------------------------------
 
 def fused_update_sweep_tree(x_tree, y_tree, nu_tree, params, mask=None, *,
-                            kind: str):
+                            kind: str, shards: ClientShards | None = None):
     """Sweep-major fused update over pytrees of (S, C, ...) leaves.
 
     ``params`` is the (S, 5) table (:func:`~repro.kernels.prox.kernel.
-    sweep_params_table`); ``mask`` an optional (S, C) cohort gate.  Returns
-    (x', nu').
+    sweep_params_table`); ``mask`` an optional (S, C) cohort gate;
+    ``shards`` the devices' split of the client dim.  Returns (x', nu').
     """
     flat_x, treedef = jax.tree_util.tree_flatten(x_tree)
     flat_y = treedef.flatten_up_to(y_tree)
     flat_nu = treedef.flatten_up_to(nu_tree)
     outs = [
-        fused_update_sweep_pallas(x, y, nu, params, mask, kind=kind)
+        fused_update_sweep_pallas(x, y, nu, params, mask, kind=kind,
+                                  shards=shards)
         for x, y, nu in zip(flat_x, flat_y, flat_nu)
     ]
     xs = jax.tree_util.tree_unflatten(treedef, [o[0] for o in outs])
@@ -87,13 +89,13 @@ def fused_update_sweep_tree(x_tree, y_tree, nu_tree, params, mask=None, *,
 
 
 def fused_tracking_sweep_tree(y_tree, g_new_tree, g_old_tree, params,
-                              mask=None):
+                              mask=None, *, shards: ClientShards | None = None):
     """Sweep-major tracking axpy over pytrees.  Returns (y', g_kept)."""
     flat_y, treedef = jax.tree_util.tree_flatten(y_tree)
     flat_gn = treedef.flatten_up_to(g_new_tree)
     flat_go = treedef.flatten_up_to(g_old_tree)
     outs = [
-        fused_tracking_sweep_pallas(y, gn, go, params, mask)
+        fused_tracking_sweep_pallas(y, gn, go, params, mask, shards=shards)
         for y, gn, go in zip(flat_y, flat_gn, flat_go)
     ]
     ys = jax.tree_util.tree_unflatten(treedef, [o[0] for o in outs])
@@ -115,7 +117,8 @@ def _broadcast_unbatched(axis_size, tree, batched):
 
 
 @functools.lru_cache(maxsize=None)
-def _make_fused_local_update(kind: str, gated: bool):
+def _make_fused_local_update(kind: str, gated: bool,
+                             shards: ClientShards | None):
     """Build the custom_vmap'd local-update entry for one prox ``kind``.
 
     The unbatched call adds a singleton config axis and runs the sweep
@@ -129,7 +132,8 @@ def _make_fused_local_update(kind: str, gated: bool):
         one = lambda tree: tm(lambda l: l[None], tree)
         m1 = mask[None] if gated else None
         xs, nus = fused_update_sweep_tree(
-            one(x), one(y), one(nu), hp_vec[None], m1, kind=kind)
+            one(x), one(y), one(nu), hp_vec[None], m1, kind=kind,
+            shards=shards)
         drop = lambda tree: tm(lambda l: l[0], tree)
         return drop(xs), drop(nus)
 
@@ -151,21 +155,22 @@ def _make_fused_local_update(kind: str, gated: bool):
             (mask,) = rest
             mb = mask if in_batched[4] else jnp.broadcast_to(
                 mask[None], (axis_size,) + mask.shape)
-        out = fused_update_sweep_tree(xb, yb, nub, hpb, mb, kind=kind)
+        out = fused_update_sweep_tree(xb, yb, nub, hpb, mb, kind=kind,
+                                      shards=shards)
         return out, tm(lambda _: True, out)
 
     return f
 
 
 @functools.lru_cache(maxsize=None)
-def _make_fused_tracking(gated: bool):
+def _make_fused_tracking(gated: bool, shards: ClientShards | None):
     """custom_vmap'd tracking entry (same dispatch as the update)."""
 
     def impl(y, g_new, g_old, hp_vec, mask):
         one = lambda tree: tm(lambda l: l[None], tree)
         m1 = mask[None] if gated else None
         ys, gs = fused_tracking_sweep_tree(
-            one(y), one(g_new), one(g_old), hp_vec[None], m1)
+            one(y), one(g_new), one(g_old), hp_vec[None], m1, shards=shards)
         drop = lambda tree: tm(lambda l: l[0], tree)
         return drop(ys), drop(gs)
 
@@ -188,7 +193,7 @@ def _make_fused_tracking(gated: bool):
             (mask,) = rest
             mb = mask if in_batched[4] else jnp.broadcast_to(
                 mask[None], (axis_size,) + mask.shape)
-        out = fused_tracking_sweep_tree(yb, gnb, gob, hpb, mb)
+        out = fused_tracking_sweep_tree(yb, gnb, gob, hpb, mb, shards=shards)
         return out, tm(lambda _: True, out)
 
     return f
@@ -203,25 +208,27 @@ def hyper_param_vec(hyper) -> jnp.ndarray:
 
 
 def fused_local_update(x_tree, y_tree, nu_tree, hp_vec, mask=None, *,
-                       kind: str):
+                       kind: str, shards: ClientShards | None = None):
     """Momentum + prox for one config's clients, sweep-major under vmap.
 
     ``hp_vec`` is the (5,) row [lam, theta, alpha, gamma, beta]; ``mask``
-    an optional (C,) cohort gate freezing rows in-kernel.  Returns
+    an optional (C,) cohort gate freezing rows in-kernel; ``shards`` the
+    devices' split of the client dim (the shard_map backend's).  Returns
     (x', nu').  Under ``jax.vmap`` over stacked configs this lowers to ONE
     sweep-major kernel whose grid axis 0 is the config axis.
     """
-    f = _make_fused_local_update(kind, mask is not None)
+    f = _make_fused_local_update(kind, mask is not None, shards)
     with annotate("fused_kernel"):
         if mask is None:
             return f(x_tree, y_tree, nu_tree, hp_vec)
         return f(x_tree, y_tree, nu_tree, hp_vec, mask)
 
 
-def fused_tracking(y_tree, g_new_tree, g_old_tree, hp_vec, mask=None):
+def fused_tracking(y_tree, g_new_tree, g_old_tree, hp_vec, mask=None, *,
+                   shards: ClientShards | None = None):
     """Tracking axpy ``y' = y + beta (g_new - g_old)`` (+ in-kernel freeze
     when ``mask`` given), sweep-major under vmap.  Returns (y', g_kept)."""
-    f = _make_fused_tracking(mask is not None)
+    f = _make_fused_tracking(mask is not None, shards)
     with annotate("fused_kernel"):
         if mask is None:
             return f(y_tree, g_new_tree, g_old_tree, hp_vec)

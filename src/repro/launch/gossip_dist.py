@@ -21,7 +21,6 @@ from __future__ import annotations
 from typing import Any
 
 import jax
-from jax.experimental.shard_map import shard_map
 
 import jax.numpy as jnp
 
@@ -81,7 +80,7 @@ def make_shardmap_mixer(placement: Placement, axes_tree: Any,
 
         out_leaves = []
         for leaf, spec in zip(flat, flat_specs):
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda blk: shard_body(plan, blk, axis_name, n),
                 mesh=mesh, in_specs=(spec,), out_specs=spec,
             )
@@ -127,7 +126,7 @@ def make_shardmap_schedule_mixer(placement: Placement, axes_tree: Any,
 
         out_leaves = []
         for leaf, spec in zip(flat, flat_specs):
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda blk: shard_schedule_body(schedule, rr, blk,
                                                 axis_name, n),
                 mesh=mesh, in_specs=(spec,), out_specs=spec,
@@ -146,7 +145,7 @@ def make_shardmap_schedule_mixer(placement: Placement, axes_tree: Any,
 
             out_leaves = []
             for leaf, spec in zip(flat, flat_specs):
-                fn = shard_map(
+                fn = jax.shard_map(
                     lambda blk: shard_compressed_qmix(schedule, rr, blk,
                                                       axis_name, n),
                     mesh=mesh, in_specs=(spec,), out_specs=spec,

@@ -1,9 +1,12 @@
-"""Production training launcher.
+"""Training launcher: DEPOSITUM over a zoo model, one process.
 
-On a real fleet this binary runs under the pod launcher with TPU devices; on
-this container it runs the same code on a host mesh (CPU devices), so
-``--mesh host`` is the default.  ``--arch`` picks any assigned architecture
-(reduced variants train end-to-end on CPU; full variants are for the fleet).
+Runs on whatever JAX finds: a TPU chip (the Pallas kernels lower through
+Mosaic) or the CPU (kernels in interpret mode).  ``--arch`` picks any
+registered architecture at its published widths; ``--reduced`` picks the
+small variant that trains end-to-end on the CPU.  ``--fused require``
+engages the fused Pallas update kernels and fails if they cannot serve a
+step.  The persistent compile cache follows
+:func:`repro.launch.compile_cache.enable_compile_cache`.
 
 Example (CPU, reduced config):
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-1.7b --reduced \
@@ -22,6 +25,7 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.core import DepositumConfig
 from repro.data import make_federated_lm_streams
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.training import save_checkpoint
 from repro.training.train_loop import (
@@ -39,7 +43,9 @@ def main():
     ap.add_argument("--clients", type=int, default=4)
     ap.add_argument("--rounds", type=int, default=50)
     ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--seq", type=int, default=64)
+    ap.add_argument("--seq", type=int, default=256,
+                    help="sequence length; a multiple of the config's "
+                         "ssm_chunk for state-space families")
     ap.add_argument("--t0", type=int, default=4, help="communication period T0")
     ap.add_argument("--alpha", type=float, default=0.02)
     ap.add_argument("--beta", type=float, default=1.0)
@@ -50,12 +56,19 @@ def main():
     ap.add_argument("--prox", default="l1",
                     choices=["l1", "mcp", "scad", "l2sq", "zero"])
     ap.add_argument("--lam", type=float, default=1e-5)
+    ap.add_argument("--fused", default="off",
+                    choices=["auto", "require", "off"],
+                    help="fused Pallas update kernels (DepositumConfig.fused)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--ckpt", default="")
     ap.add_argument("--log", default="")
     args = ap.parse_args()
 
     cfg = get_config(args.arch, reduced=args.reduced)
+    if cfg.family in ("ssm", "hybrid") and args.seq % cfg.ssm_chunk:
+        ap.error(f"--seq {args.seq} is not a multiple of {cfg.name}'s "
+                 f"ssm_chunk={cfg.ssm_chunk}")
+    enable_compile_cache()
     model = build_model(cfg)
     prox_kwargs = {"lam": args.lam}
     if args.prox in ("mcp", "scad"):
@@ -65,7 +78,7 @@ def main():
     dep = DepositumConfig(
         alpha=args.alpha, beta=args.beta, gamma=args.gamma,
         momentum=args.momentum, comm_period=args.t0,
-        prox_name=args.prox, prox_kwargs=prox_kwargs,
+        prox_name=args.prox, prox_kwargs=prox_kwargs, fused=args.fused,
     )
     tc = TrainerConfig(n_clients=args.clients, topology=args.topology,
                        depositum=dep, seed=args.seed)

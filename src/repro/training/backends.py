@@ -28,10 +28,12 @@ local-update kernel.  With ``config.use_fused_kernel`` the round program's
 update is a sweep-major Pallas kernel (``repro.kernels.prox``) whose grid
 axis 0 is the stacked-config axis; on the stacked-vmap backend the sweep
 engine's vmap maps straight onto that grid axis (one launch per leaf for
-the whole grid), while on the shard_map backend the local update runs on
-the sharded client rows — per-shard client tiles — and only mixing enters
-``shard_map``.  ``supports_fused_sweep`` advertises this; it is True for
-every in-tree backend and exists so out-of-tree placements can opt out.
+the whole grid), while on the shard_map backend each device updates its
+own client rows: the mixer carries the backend's ``client_shards`` and the
+kernel runs under ``shard_map`` with that split (a Mosaic kernel cannot be
+partitioned by XLA).  ``supports_fused_sweep`` advertises this; it is True
+for every in-tree backend and exists so out-of-tree placements can opt
+out.
 """
 from __future__ import annotations
 
@@ -40,8 +42,7 @@ from typing import Any, Callable, Optional, Protocol, runtime_checkable
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.core.mixing import MixPlan, as_mixer, shard_body
 from repro.core.schedule import (
@@ -52,6 +53,7 @@ from repro.core.schedule import (
     shard_schedule_body,
     wire_supported,
 )
+from repro.kernels.prox.kernel import ClientShards
 
 Mixer = Callable[[Any], Any]
 
@@ -81,6 +83,11 @@ class ExecutionBackend(Protocol):
     def mixer_for(self, plan: MixPlan) -> Mixer:  # pragma: no cover
         ...
 
+    def place(self, state: Any) -> Any:  # pragma: no cover
+        """Put a state (leaves with a leading client dim) where the
+        backend's round program expects it."""
+        ...
+
 
 @dataclasses.dataclass(frozen=True)
 class StackedVmapBackend:
@@ -100,6 +107,9 @@ class StackedVmapBackend:
                 lambda tree, r: apply_schedule(plan, r, tree), plan)
         return as_mixer(plan)
 
+    def place(self, state):
+        return state
+
 
 @dataclasses.dataclass(frozen=True)
 class ShardMapBackend:
@@ -115,9 +125,13 @@ class ShardMapBackend:
     axis_name: str = "clients"
     n_clients: int = 0
     name: str = dataclasses.field(default="shard_map", init=False)
-    #: The fused local update runs on the sharded client rows *outside*
-    #: the shard_map'd mixing — per-shard client tiles, same kernel.
+    #: The fused local update runs on each device's client rows, in its
+    #: own shard_map (``client_shards``) outside the mixing.
     supports_fused_sweep: bool = dataclasses.field(default=True, init=False)
+
+    @property
+    def client_shards(self) -> ClientShards:
+        return ClientShards(self.mesh, self.axis_name)
 
     def _axis_size(self) -> int:
         if isinstance(self.axis_name, tuple):
@@ -140,25 +154,37 @@ class ShardMapBackend:
                 f"got n_clients={n} on a {size}-way axis — use a dense plan")
         return size, n
 
+    def place(self, state):
+        """Shard every leaf's leading client dim over the axis (scalars,
+        such as the iteration counter, are replicated)."""
+        def put(leaf):
+            spec = P(self.axis_name) if jnp.ndim(leaf) else P()
+            return jax.device_put(leaf, NamedSharding(self.mesh, spec))
+
+        return jax.tree_util.tree_map(put, state)
+
     def mixer_for(self, plan) -> Mixer:
         if isinstance(plan, MixSchedule):
             return self._schedule_mixer(plan)
         if plan.kind == "identity":
-            return lambda tree: tree
-        size, _n = self._check_plan(plan)
-        spec_axis = self.axis_name
+            def mix(tree):
+                return tree
+        else:
+            size, _n = self._check_plan(plan)
+            spec_axis = self.axis_name
 
-        def mix(tree):
-            def leaf(x):
-                spec = P(spec_axis)
-                fn = shard_map(
-                    lambda blk: shard_body(plan, blk, spec_axis, size),
-                    mesh=self.mesh, in_specs=(spec,), out_specs=spec,
-                )
-                return fn(x)
+            def mix(tree):
+                def leaf(x):
+                    spec = P(spec_axis)
+                    fn = jax.shard_map(
+                        lambda blk: shard_body(plan, blk, spec_axis, size),
+                        mesh=self.mesh, in_specs=(spec,), out_specs=spec,
+                    )
+                    return fn(x)
 
-            return jax.tree_util.tree_map(leaf, tree)
+                return jax.tree_util.tree_map(leaf, tree)
 
+        mix.client_shards = self.client_shards  # read by depositum.step
         return mix
 
     def _schedule_mixer(self, sched: MixSchedule) -> Mixer:
@@ -181,7 +207,7 @@ class ShardMapBackend:
 
             def leaf(x):
                 spec = P(spec_axis)
-                fn = shard_map(
+                fn = jax.shard_map(
                     lambda blk: shard_schedule_body(sched, rr, blk,
                                                     spec_axis, size),
                     mesh=self.mesh, in_specs=(spec,), out_specs=spec,
@@ -197,7 +223,7 @@ class ShardMapBackend:
 
                 def leaf(x):
                     spec = P(spec_axis)
-                    fn = shard_map(
+                    fn = jax.shard_map(
                         lambda blk: shard_compressed_qmix(sched, rr, blk,
                                                           spec_axis, size),
                         mesh=self.mesh, in_specs=(spec,), out_specs=spec,
@@ -206,7 +232,8 @@ class ShardMapBackend:
 
                 return jax.tree_util.tree_map(leaf, tree)
 
-        return ScheduleMixer(mix, sched, wire_fn=wire)
+        return ScheduleMixer(mix, sched, wire_fn=wire,
+                             client_shards=self.client_shards)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -229,6 +256,9 @@ class SweepBackend:
 
     def mixer_for(self, plan: MixPlan) -> Mixer:
         return self.inner.mixer_for(plan)
+
+    def place(self, state):
+        return self.inner.place(state)
 
     def run(self, params0, grad_fn, config, mixer, hypers, batches, *,
             n_clients: int, metrics_fn=None, batch_axis=None,
@@ -311,7 +341,10 @@ def suggest_backend(plan_or_schedule, n_clients: int, *,
     name = suggest_backend_name(_plan_kind(plan_or_schedule), n_clients,
                                 len(devices), wire_bytes=wire_bytes)
     if name == "shard_map":
-        mesh = jax.make_mesh((len(devices),), (axis_name,), devices=devices)
+        # Auto axes: the round program stays in XLA's propagation mode (the
+        # model zoo's reshapes have no explicit-sharding rules)
+        mesh = jax.make_mesh((len(devices),), (axis_name,),
+                             axis_types=(AxisType.Auto,), devices=devices)
         return ShardMapBackend(mesh=mesh, axis_name=axis_name,
                                n_clients=n_clients)
     return StackedVmapBackend()

@@ -88,11 +88,12 @@ class FederatedTrainer:
         # sync-equivalence pin compares trajectories bit for bit)
         grad_fn = make_value_grad_fn(model)
         self._grad_fn = grad_fn
+        # the round consumes its input state (donated): at model widths two
+        # live copies of every client's x, y, nu, mu and g do not fit
         self._round = jax.jit(
             lambda state, batches: local_then_comm_round(
                 state, batches, grad_fn, cfg.depositum, self.mixer
-            )
-        )
+            ), donate_argnums=0)
 
         if telemetry is True:
             telemetry = Telemetry.memory()
@@ -116,11 +117,21 @@ class FederatedTrainer:
             # carry: state trajectories are bit-identical to metrics-off.
             # log_every / force are traced operands — cadence toggles
             # cannot recompile (pinned by tests/test_obs.py).
-            self._round_tel = jax.jit(round_tel)
+            self._round_tel = jax.jit(round_tel, donate_argnums=0)
 
     def init_state(self, key) -> DepositumState:
+        """Initial state, placed where the backend runs the round."""
         params, _axes = self.model.init(key)
-        return dep_init(params, self.cfg.n_clients)
+        return self.backend.place(dep_init(params, self.cfg.n_clients))
+
+    def lower_round(self, state: DepositumState, batches):
+        """Lower the round program for these shapes (no telemetry).
+
+        ``.compile()`` on the result compiles ahead of time, and later
+        rounds of :meth:`run` with the same shapes reuse that executable;
+        its ``as_text()`` and ``memory_analysis()`` describe the device
+        program."""
+        return self._round.lower(state, batches)
 
     def _logged_rounds(self, n_rounds: int) -> list[int]:
         """Explicit cadence: 1-based rounds that land in history — every
@@ -143,6 +154,9 @@ class FederatedTrainer:
         profile_dir: Optional[str] = None,
     ) -> tuple[DepositumState, list[dict]]:
         """batch_iter yields pytrees with leaves (T0, n_clients, B, ...).
+
+        ``state`` is consumed (its buffers are donated to the round
+        program); continue from the returned state.
 
         History has one record per :meth:`_logged_rounds` entry with
         ``round``, ``wall_s``, ``loss`` (the model's scalar loss aux,

@@ -44,13 +44,12 @@ def test_ppermute_gossip_equals_dense_mix():
         W = mixing_matrix("ring", n)
         dense = jax.jit(lambda t: make_dense_mixer(W)(t))(xs)
 
-        from jax.experimental.shard_map import shard_map
         def body(blk):
             perm_f = [((s + 1) % n, s) for s in range(n)]
             perm_b = [((s - 1) % n, s) for s in range(n)]
             return (blk + jax.lax.ppermute(blk, "data", perm_f)
                     + jax.lax.ppermute(blk, "data", perm_b)) / 3.0
-        pp = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("data"),),
+        pp = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("data"),),
                                out_specs=P("data")))(xs)
         err = float(jnp.max(jnp.abs(dense - pp)))
         assert err < 1e-5, err

@@ -13,6 +13,7 @@ programs lower to Mosaic on TPU):
   take the fused path, and that ``"require"`` raises on ineligibility.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +35,7 @@ from repro.core import (
 )
 from repro.kernels.prox.kernel import (
     TRACE_COUNTS,
+    ClientShards,
     fused_tracking_sweep_pallas,
     fused_update_sweep_pallas,
     sweep_layout,
@@ -75,7 +77,29 @@ def _ref_rows(x, y, nu, params, kind):
     return jnp.stack(xs), jnp.stack(nus)
 
 
-@pytest.mark.parametrize("shape", SHAPES)
+# leaf layouts of the (R, K) view: a trailing dim that is a multiple of
+# 128 split evenly into two row tiles, and a ragged leaf whose row and
+# column tiles both end in a partial edge block
+LAYOUT_SHAPES = [(1024, 256), (70, 4100)]
+
+
+@functools.partial(jax.jit, static_argnames="kind")
+def _ref_rows_f32(x, y, nu, params, kind):
+    """Per-config reference with f32 hyperparameters, exactly as the SMEM
+    table holds them (Python floats would round ``1 - gamma`` in f64),
+    compiled as one program like the kernel body (op-by-op dispatch
+    rounds the fused expressions differently)."""
+    xs, nus = [], []
+    for s in range(x.shape[0]):
+        lam, theta, alpha, gamma, _ = (params[s, i] for i in range(5))
+        xr, nur = fused_update_ref(x[s], y[s], nu[s], lam, alpha, gamma,
+                                   prox_kind=kind, theta=theta)
+        xs.append(xr)
+        nus.append(nur)
+    return jnp.stack(xs), jnp.stack(nus)
+
+
+@pytest.mark.parametrize("shape", SHAPES + LAYOUT_SHAPES)
 @pytest.mark.parametrize("kind", ["l1", "mcp", "scad"])
 def test_sweep_kernel_matches_oracle(kind, shape):
     key = jax.random.PRNGKey(hash((kind, shape)) % 2**31)
@@ -89,6 +113,10 @@ def test_sweep_kernel_matches_oracle(kind, shape):
                                atol=1e-6, rtol=1e-6)
     np.testing.assert_allclose(np.asarray(nuo), np.asarray(nur),
                                atol=1e-6, rtol=1e-6)
+    if shape in LAYOUT_SHAPES:  # the in-place view is bit-exact
+        xb, nub = _ref_rows_f32(x, y, nu, params, kind)
+        np.testing.assert_array_equal(np.asarray(xo), np.asarray(xb))
+        np.testing.assert_array_equal(np.asarray(nuo), np.asarray(nub))
 
 
 @pytest.mark.parametrize("kind", ["l1", "mcp", "scad"])
@@ -118,10 +146,10 @@ def test_sweep_kernel_mask_freezes_rows_bit_exact(kind):
                                               np.asarray(nu[s, c]))
 
 
+@pytest.mark.parametrize("shape", [(257,)] + LAYOUT_SHAPES)
 @pytest.mark.parametrize("gated", [False, True])
-def test_tracking_sweep_matches_oracle(gated):
+def test_tracking_sweep_matches_oracle(gated, shape):
     key = jax.random.PRNGKey(21)
-    shape = (257,)
     y = _make(key, shape)
     gn = _make(jax.random.fold_in(key, 1), shape)
     go = _make(jax.random.fold_in(key, 2), shape)
@@ -129,8 +157,15 @@ def test_tracking_sweep_matches_oracle(gated):
     mask = (jnp.asarray([[1, 0, 1, 1], [0, 1, 1, 0], [1, 1, 1, 1]],
                         jnp.float32) if gated else None)
     yo, gk = fused_tracking_sweep_pallas(y, gn, go, params, mask)
-    beta = np.asarray(params)[:, 4].reshape(S, 1, 1)
+    beta = np.asarray(params)[:, 4].reshape((S, 1) + (1,) * len(shape))
     yr = np.asarray(y) + beta * (np.asarray(gn) - np.asarray(go))
+    if shape in LAYOUT_SHAPES:  # the in-place view is bit-exact
+        yb = np.asarray(jax.jit(lambda a, b, c, p: a + p[:, 4].reshape(
+            beta.shape) * (b - c))(y, gn, go, params))
+        live = (np.ones((S, C)) if mask is None else np.asarray(mask)) > 0
+        want = np.where(live.reshape(live.shape + (1,) * len(shape)), yb,
+                        np.asarray(y))
+        np.testing.assert_array_equal(np.asarray(yo), want)
     if not gated:
         np.testing.assert_allclose(np.asarray(yo), yr, atol=1e-6, rtol=1e-6)
         np.testing.assert_array_equal(np.asarray(gk), np.asarray(gn))
@@ -150,11 +185,48 @@ def test_tracking_sweep_matches_oracle(gated):
                                               np.asarray(go[s, c]))
 
 
+@pytest.mark.parametrize("gated", [False, True])
+def test_client_shards_match_unsharded_bit_exact(gated):
+    """With ``ClientShards`` the kernels run under ``shard_map`` over the
+    client dim (the shard_map backend's split); per device they are the
+    same kernel, so the result is bit-identical to the unsharded call."""
+    mesh = jax.make_mesh((1,), ("clients",))
+    shards = ClientShards(mesh, "clients")
+    key = jax.random.PRNGKey(31)
+    shape = (70, 130)
+    x, y, nu = (_make(jax.random.fold_in(key, i), shape) for i in range(3))
+    params = _table()
+    mask = (jnp.asarray([[1, 0, 1, 1], [0, 1, 1, 0], [1, 1, 1, 1]],
+                        jnp.float32) if gated else None)
+
+    def run(sh):
+        return (fused_update_sweep_pallas(x, y, nu, params, mask, kind="mcp",
+                                          shards=sh)
+                + fused_tracking_sweep_pallas(y, x, nu, params, mask,
+                                              shards=sh))
+
+    for a, b in zip(run(shards), run(None)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_sweep_layout_tiles():
-    for d, rows in [(1, 8), (128, 8), (1025, 16), (128 * 256, 256)]:
-        lay = sweep_layout(d)
-        assert lay.rows == rows and lay.rows % lay.block_rows == 0
-        assert lay.padded >= d and lay.padded % (8 * 128) == 0
+    """Leaves are viewed in place as (R, K) — K the leaf's own last dim —
+    and tiled by full dims or aligned blocks; nothing is padded."""
+    for shape, (rows, cols, br, bc) in [
+            ((), (1, 1, 1, 1)),
+            ((1025,), (1, 1025, 1, 1025)),
+            ((24, 24), (24, 24, 24, 24)),
+            ((50432, 768), (50432, 768, 128, 768)),
+            ((24, 768, 3352), (18432, 3352, 32, 3352)),
+            ((1179648,), (1, 1179648, 1, 4096))]:
+        lay = sweep_layout(shape)
+        assert (lay.rows, lay.cols, lay.block_rows, lay.block_cols) == (
+            rows, cols, br, bc), shape
+        assert lay.size == int(np.prod(shape, dtype=np.int64))
+        if br != rows:  # a tiled dim is sublane-aligned for f32 and bf16
+            assert br % 16 == 0
+        if bc != cols:
+            assert bc % 128 == 0
 
 
 def test_params_swap_does_not_retrace():

@@ -1,7 +1,6 @@
 """Sharding-rule logic (pure python, no multi-device compile needed)."""
-import jax
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.launch.sharding import (
     POD_AS_CLIENT_ARCHS,
@@ -12,25 +11,15 @@ from repro.launch.sharding import (
 pytestmark = pytest.mark.filterwarnings("ignore")
 
 
-def _abstract_mesh(sizes, names):
-    """AbstractMesh across jax versions: (sizes, names) vs ((name, size), ...)."""
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
 @pytest.fixture(scope="module")
 def mesh():
     # 1-device "mesh" cannot express 16x16; use an abstract mesh instead
-    return _abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 @pytest.fixture(scope="module")
 def multi_mesh():
-    return _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_replicated_placement_basics(mesh):
