@@ -69,7 +69,7 @@ PyTree = Any
 
 
 def _scoped(name, fn):
-    """fn under a profiler/named scope (trace-time metadata only)."""
+    """fn under a phase's named scope (trace-time metadata only)."""
     def wrapped(*args):
         with annotate(name):
             return fn(*args)
@@ -424,7 +424,8 @@ def step(
         x_next, mem_x = _gated_choco(x_half, state.comm["x"], key_x)
 
     # (3) fresh minibatch gradients at the *new* iterate
-    g_next, aux = grad_fn(x_next, batch)
+    with annotate("fwd_bwd"):
+        g_next, aux = grad_fn(x_next, batch)
 
     # (4) gradient tracking with step size beta
     if fused_ok:

@@ -9,9 +9,9 @@ Three pieces:
 * :mod:`repro.obs.record` — *how* to record it: a ring buffer riding the
   ``lax.scan`` carry, flushed through ``io_callback`` into sinks, with
   cadence and config tags as runtime operands (zero retraces).
-* :mod:`repro.obs.trace` / :mod:`repro.obs.sinks` — named-scope /
-  profiler annotations, blocked-vs-dispatch timers, and the pluggable
-  JSONL / CSV / in-memory event sinks.
+* :mod:`repro.obs.trace` / :mod:`repro.obs.sinks` — named scopes on
+  device ops, profiler spans on host time, blocked-vs-dispatch timing, and
+  the pluggable JSONL / CSV / in-memory event sinks.
 
 Attributes resolve lazily (PEP 562): ``repro.core`` modules annotate
 their phases via :mod:`repro.obs.trace` while :mod:`repro.obs.metrics`
@@ -28,8 +28,8 @@ _EXPORTS = {
     "Telemetry": "record", "TelemetryCarry": "record",
     "CsvSink": "sinks", "JsonlSink": "sinks", "MemorySink": "sinks",
     "validate_event": "sinks", "validate_jsonl": "sinks",
-    "PHASES": "trace", "RoundTimer": "trace", "Timing": "trace",
-    "annotate": "trace", "profile_capture": "trace", "time_fn": "trace",
+    "PHASES": "trace", "Timing": "trace", "annotate": "trace",
+    "profile_capture": "trace", "span": "trace", "time_fn": "trace",
 }
 
 __all__ = sorted(_EXPORTS)

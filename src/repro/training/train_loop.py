@@ -28,7 +28,7 @@ from repro.launch.steps import make_value_grad_fn
 from repro.models.registry import Model
 from repro.obs.metrics import round_values
 from repro.obs.record import Telemetry
-from repro.obs.trace import RoundTimer, profile_capture
+from repro.obs.trace import profile_capture, span
 from repro.training.backends import ExecutionBackend, suggest_backend
 
 
@@ -88,21 +88,28 @@ class FederatedTrainer:
         # sync-equivalence pin compares trajectories bit for bit)
         grad_fn = make_value_grad_fn(model)
         self._grad_fn = grad_fn
+        #: times this trainer's round program was traced (at trace time,
+        #: so free at run time): above 1, a round recompiled, e.g. for a
+        #: batch of another shape
+        self.round_traces = 0
+
+        def round_fn(state, batches):
+            self.round_traces += 1
+            return local_then_comm_round(state, batches, grad_fn,
+                                         cfg.depositum, self.mixer)
+
         # the round consumes its input state (donated): at model widths two
         # live copies of every client's x, y, nu, mu and g do not fit
-        self._round = jax.jit(
-            lambda state, batches: local_then_comm_round(
-                state, batches, grad_fn, cfg.depositum, self.mixer
-            ), donate_argnums=0)
+        self._round = jax.jit(round_fn, donate_argnums=0)
 
         if telemetry is True:
             telemetry = Telemetry.memory()
         self.telemetry = telemetry or None
-        self.timer = RoundTimer()
         if self.telemetry is not None:
             tel = self.telemetry
 
             def round_tel(state, batches, carry, log_every, force):
+                self.round_traces += 1
                 state, aux = local_then_comm_round(
                     state, batches, grad_fn, cfg.depositum, self.mixer)
                 r = (state.t - 1) // cfg.depositum.comm_period
@@ -164,47 +171,62 @@ class FederatedTrainer:
         attached, the recorded metric streams (consensus errors,
         prox-gradient norm, bytes-on-wire, ...) merge in by round.
         ``profile_dir`` opts into a ``jax.profiler.trace`` capture of the
-        whole loop.  ``self.timer`` accumulates blocked-vs-dispatch round
-        times across the run.
+        whole loop.  Under any profiler capture the loop records host
+        spans: ``trainer.round`` (arg ``round``, 1-based) over each round,
+        with children ``trainer.next_batch`` (``next(batch_iter)``),
+        ``trainer.dispatch`` (the jitted round call) and, on logged rounds,
+        ``trainer.log_sync`` (loss readback and ``eval_fn``); then
+        ``trainer.drain`` over the final wait for the state.
         """
         tel = self.telemetry
         logged = set(self._logged_rounds(n_rounds))
         history: list[dict] = []
         by_round: dict[int, dict] = {}
         t0 = time.perf_counter()
-        timer = self.timer
         carry = tel.init_carry() if tel is not None else None
         with profile_capture(profile_dir, enabled=profile_dir is not None):
-            for r in range(n_rounds):
-                batches = next(batch_iter)
-                with timer.round():
-                    if tel is None:
-                        state, aux = self._round(state, batches)
-                    else:
-                        state, aux, carry = self._round_tel(
-                            state, batches, carry, self.cfg.log_every,
-                            r == n_rounds - 1)
-                if (r + 1) in logged:
-                    rec = {"round": r + 1,
-                           "wall_s": time.perf_counter() - t0}
-                    loss = None
-                    if isinstance(aux, dict):
-                        loss = aux.get("ce", aux.get("loss"))
-                    if loss is not None:
-                        rec["loss"] = float(jnp.mean(loss))
-                    if eval_fn is not None:
-                        rec.update(eval_fn(state, r + 1))
-                    by_round[r + 1] = rec
-                    history.append(rec)
-        timer.block_on(state)
+            for r in range(1, n_rounds + 1):
+                with span("trainer.round", round=r):
+                    with span("trainer.next_batch", round=r):
+                        batches = next(batch_iter)
+                    with span("trainer.dispatch", round=r):
+                        if tel is None:
+                            state, aux = self._round(state, batches)
+                        else:
+                            state, aux, carry = self._round_tel(
+                                state, batches, carry, self.cfg.log_every,
+                                r == n_rounds)
+                    if r in logged:
+                        with span("trainer.log_sync", round=r):
+                            rec = self._record(r, time.perf_counter() - t0,
+                                               aux, state, eval_fn)
+                        by_round[r] = rec
+                        history.append(rec)
+            with span("trainer.drain"):
+                jax.block_until_ready(state)
+                if tel is not None:
+                    tel.sync()
         if tel is not None:
-            tel.sync()
             for event in tel.events(0):
                 rec = by_round.get(event["round"])
                 if rec is not None:
                     rec.update((k, v) for k, v in event.items()
                                if k not in ("config", "round"))
         return state, history
+
+    @staticmethod
+    def _record(r, wall_s, aux, state, eval_fn) -> dict:
+        """A logged round's history record; reads the loss back from the
+        device (a sync with the round program)."""
+        rec = {"round": r, "wall_s": wall_s}
+        loss = None
+        if isinstance(aux, dict):
+            loss = aux.get("ce", aux.get("loss"))
+        if loss is not None:
+            rec["loss"] = float(jnp.mean(loss))
+        if eval_fn is not None:
+            rec.update(eval_fn(state, r))
+        return rec
 
     def mean_params(self, state: DepositumState):
         """Consensus (client-averaged) model for evaluation/serving."""

@@ -13,12 +13,17 @@ The contracts under test, in order:
   counts pinned on both the trainer round and the sweep runner);
 * the trainer's history has no silent gaps: off-cadence runs still record
   the final round, and ``loss`` survives models whose aux has no ``"ce"``;
+* the trainer's host spans (``trainer.*``) nest inside their round in a
+  profiler capture, and ``round_traces`` counts the round program's traces;
 * (slow) on a composite quadratic the recorded prox-gradient and
   consensus-error streams are decreasing in running mean — the O(1/T)
   sanity check of Theorem 1.
 """
+import glob
 import json
+import os
 import textwrap
+from collections import Counter
 from typing import NamedTuple
 
 import jax
@@ -45,6 +50,7 @@ from repro.obs.metrics import (
 )
 from repro.obs.record import Telemetry
 from repro.obs.sinks import JsonlSink, MemorySink, validate_event, validate_jsonl
+from repro.obs.trace import PHASES
 from repro.training.backends import StackedVmapBackend
 from repro.training.sweep import _scanned_run, sweep_run
 from repro.training.train_loop import FederatedTrainer, TrainerConfig
@@ -433,12 +439,66 @@ def test_trainer_history_cadence_is_explicit():
         assert rec["wire_bytes"] == N * (N - 1) * D * 4 * 2
 
 
-def test_trainer_timer_accumulates():
+# ---------------------------------------------------------------------------
+# Trainer host spans and the round-trace counter
+# ---------------------------------------------------------------------------
+
+TRAINER_CHILDREN = ("trainer.next_batch", "trainer.dispatch",
+                    "trainer.log_sync")
+
+
+def _host_events(log_dir):
+    """(name, start_ns, end_ns, args) of every host event in a capture."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                         dict(e.stats)) for e in line.events]
+    return out
+
+
+def test_trainer_spans_nest_per_round(tmp_path):
+    """run(profile_dir=) records one trainer.round per round, its children
+    inside it with the same round id, one trainer.drain after the last, and
+    no host span named after a device phase (those are scopes only)."""
     cfg = TrainerConfig(n_clients=N, depositum=_cfg(), log_every=1)
     tr = FederatedTrainer(_toy_model(), cfg, schedule=_sched())
-    tr.run(tr.init_state(jax.random.PRNGKey(0)), _trainer_batches(3), 3)
-    t = tr.timer.timing()
-    assert t.blocked_us > 0 and tr.timer.rounds == 3
+    tr.run(tr.init_state(jax.random.PRNGKey(0)), _trainer_batches(3), 3,
+           profile_dir=str(tmp_path))
+    events = _host_events(str(tmp_path))
+    count = Counter(e[0] for e in events)
+    for name in ("trainer.round",) + TRAINER_CHILDREN:
+        assert count[name] == 3, (name, count[name])
+    assert count["trainer.drain"] == 1
+    rounds = {e[3]["round"]: e for e in events if e[0] == "trainer.round"}
+    assert sorted(rounds) == [1, 2, 3]
+    for name, start, end, args in events:
+        if name in TRAINER_CHILDREN:
+            _, r_start, r_end, _ = rounds[args["round"]]
+            assert r_start <= start <= end <= r_end, (name, args)
+    (drain,) = [e for e in events if e[0] == "trainer.drain"]
+    assert drain[1] >= rounds[3][2]
+    assert not set(count) & set(PHASES)
+
+
+def test_round_traces_counts_retraces():
+    cfg = TrainerConfig(n_clients=N, depositum=_cfg(), log_every=1)
+    tr = FederatedTrainer(_toy_model(), cfg, schedule=_sched())
+    state, _ = tr.run(tr.init_state(jax.random.PRNGKey(0)),
+                      _trainer_batches(3), 3)
+    assert tr.round_traces == 1
+
+    def longer():
+        while True:
+            yield jnp.zeros((T0, N, 2))
+
+    tr.run(state, longer(), 1)
+    assert tr.round_traces == 2
 
 
 # ---------------------------------------------------------------------------
