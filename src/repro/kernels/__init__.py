@@ -18,3 +18,13 @@ def interpret_mode() -> bool:
     raise RuntimeError(
         f"Pallas kernels run on 'tpu' (Mosaic) or 'cpu' (interpret mode), "
         f"not on {backend!r}")
+
+
+def layout_device():
+    """The device whose default array layouts the kernels' views follow
+    under Mosaic: the first device of the default backend.
+
+    Read at trace time, like :func:`interpret_mode`, so a test that
+    compiles for a described TPU from a CPU process steers it here.
+    """
+    return jax.devices()[0]

@@ -34,7 +34,9 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.layout import Layout
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -45,9 +47,11 @@ BLOCK_ELEMS = 128 * 1024  # target elements per operand block (512 KiB f32)
 MAX_BLOCK_COLS = 4096    # widest column block (a multiple of LANE)
 MIN_BLOCK_ROWS = 32      # multiple of every float sublane tile (f32 8, bf16 16)
 
-# trace-time call counters, keyed by kernel family.  Incremented inside the
-# jitted wrappers, so a count rises only when XLA actually (re)traces —
-# the regression tests pin "zero retraces across configs" with these.
+# trace-time call counters, keyed by kernel family, and "permuted_view"
+# (leaves viewed in a non-row-major device layout, one per leaf and call).
+# Incremented inside the jitted wrappers, so a count rises only when XLA
+# actually (re)traces — the regression tests pin "zero retraces across
+# configs" with these.
 TRACE_COUNTS: collections.Counter = collections.Counter()
 
 
@@ -90,18 +94,30 @@ def _prox_block(x, kind: str, lam, theta, alpha):
 # Sweep-major layout: the (config, client) axes live IN the grid
 # ---------------------------------------------------------------------------
 #
-# Layout per leaf: (S, C, *param_shape) is viewed as (S, C, R, K) with K the
-# leaf's own last dim and R the product of its other per-client dims.  That
-# view merges major dims only, so it never relayouts the minor (lane) dim:
-# no flat (S, C, d) intermediate and no padding copy.  (A flat intermediate
-# of the 50 280 x 768 embedding stalls the TPU compiler.)  Blocks are
-# (1, 1, block_rows, block_cols): a full dim where it fits the block
-# budget, else an aligned tile, with the grid rounding up; Pallas masks the
-# ragged edge blocks.  The SMEM params table is (S, 5)
-# [lam, theta, alpha, gamma, beta] indexed by pl.program_id(0); the optional
-# cohort gate is an (S, C) SMEM table indexed by (program_id(0),
-# program_id(1)) — masked (config, client) rows are written back unchanged
-# inside the kernel, no post-hoc HBM sweep.
+# Layout per leaf: (S, C, *param_shape) is viewed as (S, C, R, K) in the
+# axis order in which the device already stores the leaf (``view_order``:
+# the default layout of the operand's shape on the backend, read once per
+# shape and dtype).  K is the leaf's minor dim in that order and R the
+# product of its other per-client dims.  The operands are transposed into
+# that order before the reshape and the outputs transposed back, so both
+# steps only rename the bytes in place (XLA emits bitcasts): no relayout
+# copy into the kernel, none out of it, no flat (S, C, d) intermediate
+# and no padding copy.  (A flat intermediate of the 50 280 x 768
+# embedding stalls the TPU compiler.)  A TPU may store a leaf whose last
+# dim is not a multiple of 128 with another dim minor: mamba2-130m's
+# in_proj (24, 768, 3352) is kept 768-minor.  The view falls back to
+# row-major, K the leaf's own last dim, where the default order is
+# row-major (every leaf on the CPU, in interpret mode) or where it does not
+# keep (S, C) major: the grid's client axis and the cohort gate need them
+# major.  (A rank-1 leaf, stored (C, K) with its clients in the sublanes of
+# one tile, is still retiled to a tile per client: C x K elements.)
+# Blocks are (1, 1, block_rows, block_cols): a full dim where it
+# fits the block budget, else an aligned tile, with the grid rounding up;
+# Pallas masks the ragged edge blocks.  The SMEM params table is (S, 5)
+# [lam, theta, alpha, gamma, beta] indexed by pl.program_id(0); the
+# optional cohort gate is an (S, C) SMEM table indexed by (program_id(0),
+# program_id(1)) — masked (config, client) rows are written back
+# unchanged inside the kernel, no post-hoc HBM sweep.
 
 # params-table column order (shared with ops.py / depositum.step)
 PARAM_COLS = ("lam", "theta", "alpha", "gamma", "beta")
@@ -212,11 +228,39 @@ def _tracking_sweep_kernel(p_ref, *refs, gated):
     yo_ref[0, 0] = y_next.astype(yo_ref.dtype)
 
 
+@functools.lru_cache(maxsize=None)
+def _default_order(shape: tuple[int, ...], dtype: np.dtype,
+                   device) -> tuple[int, ...]:
+    """Major-to-minor axis order of ``device``'s default layout for an
+    array of ``shape`` and ``dtype``, if it keeps axes 0 and 1 (S, C)
+    major; else the row-major order."""
+    pjrt = device.client.get_default_layout(dtype, shape, device)
+    order = tuple(Layout.from_pjrt_layout(pjrt).major_to_minor)
+    return order if order[:2] == (0, 1) else tuple(range(len(shape)))
+
+
+def view_order(shape: tuple[int, ...], dtype) -> tuple[int, ...]:
+    """Axis order in which the kernels view an (S, C, *p) operand: the
+    order the device stores it in under Mosaic, row-major in interpret
+    mode (see the layout notes above)."""
+    if kernels.interpret_mode():
+        return tuple(range(len(shape)))
+    return _default_order(tuple(shape), np.dtype(dtype),
+                          kernels.layout_device())
+
+
 def _grid_call_local(kernel, out_dtypes, params, mask, x, *operands):
     """One pallas_call over (S, C, *p) leaves held by one device."""
     S, C = x.shape[:2]
-    lay = sweep_layout(tuple(x.shape[2:]))
-    views = [a.reshape(S, C, lay.rows, lay.cols) for a in (x,) + operands]
+    order = view_order(x.shape, x.dtype)
+    permuted = order != tuple(range(x.ndim))
+    leaves = (x,) + operands
+    if permuted:
+        TRACE_COUNTS["permuted_view"] += 1
+        leaves = tuple(jnp.transpose(a, order) for a in leaves)
+    shape = leaves[0].shape
+    lay = sweep_layout(tuple(shape[2:]))
+    views = [a.reshape(S, C, lay.rows, lay.cols) for a in leaves]
     bs = pl.BlockSpec((1, 1, lay.block_rows, lay.block_cols),
                       lambda s, c, i, j: (s, c, i, j))
     smem = [_scalar_spec()]
@@ -233,7 +277,11 @@ def _grid_call_local(kernel, out_dtypes, params, mask, x, *operands):
                    for dt in out_dtypes],
         interpret=kernels.interpret_mode(),
     )(*ins, *views)
-    return tuple(o.reshape(x.shape) for o in outs)
+    outs = tuple(o.reshape(shape) for o in outs)
+    if permuted:
+        inverse = tuple(order.index(i) for i in range(len(order)))
+        outs = tuple(jnp.transpose(o, inverse) for o in outs)
+    return outs
 
 
 class ClientShards(NamedTuple):

@@ -17,6 +17,15 @@ Two entry levels:
   stacked configs (``repro.training.sweep``) executes ONE sweep-major
   kernel launch per leaf instead of S per-config launches, with zero
   retraces across configs.
+
+Every entry reaches the kernels with (S, C, *param_shape) leaves, which the
+kernels view in the axis order the backend's default layout stores them in
+(``kernel.view_order``): under Mosaic on a TPU, mamba2-130m's in_proj
+(24, 768, 3352) is read 768-minor as the chip keeps it, so no relayout copy
+goes into or out of the kernel.  The view is row-major where that layout is
+(every leaf on the CPU, in interpret mode) or where it would not keep the
+(S, C) axes major.  Element order changes nothing of an elementwise
+update, so results do not depend on the view.
 """
 from __future__ import annotations
 

@@ -209,6 +209,80 @@ def test_client_shards_match_unsharded_bit_exact(gated):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+# a forced device layout: (order, per-client leaf shape); the first swaps
+# the last two dims (in_proj on a TPU), the second moves the last dim
+# above the other two
+PERMUTED = [((0, 1, 3, 2), (70, 130)), ((0, 1, 4, 2, 3), (6, 20, 33))]
+
+
+@pytest.fixture
+def forced_order(monkeypatch):
+    """Make the kernels view (S, C, *p) operands of a given rank in a given
+    axis order, as they do under Mosaic where the device stores a leaf
+    that way; traces are cleared on both sides, since they are cached by
+    shape and not by order."""
+    from repro.kernels.prox import kernel
+
+    def force(order):
+        monkeypatch.setattr(
+            kernel, "view_order",
+            lambda shape, dtype: (order if len(shape) == len(order)
+                                  else tuple(range(len(shape)))))
+        jax.clear_caches()
+
+    yield force
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("order,shape", PERMUTED, ids=["swap", "roll"])
+@pytest.mark.parametrize("gated", [False, True])
+def test_permuted_view_bit_exact(forced_order, order, shape, gated):
+    """Viewing the leaves in another axis order only reorders the kernel's
+    elementwise work: through the round program's entries, vmapped over
+    S = 2 configs (the sweep rule), with and without the cohort gate, both
+    kernels equal the row-major view and the reference bit for bit, and
+    each trace of a kernel counts its one permuted leaf once."""
+    from repro.kernels.prox.ops import fused_local_update, fused_tracking
+
+    key = jax.random.PRNGKey(41)
+    x, y, nu = (_make(jax.random.fold_in(key, i), shape)[:2]
+                for i in range(3))
+    params = _table()[:2]
+    mask = (jnp.asarray([[1, 0, 1, 1], [0, 1, 1, 0]], jnp.float32)
+            if gated else None)
+    m = () if mask is None else (mask,)
+
+    def run():
+        xo, nuo = jax.vmap(lambda a, b, c, p, *g: fused_local_update(
+            a, b, c, p, *g, kind="scad"))(x, y, nu, params, *m)
+        yo, gk = jax.vmap(fused_tracking)(y, x, nu, params, *m)
+        return xo, nuo, yo, gk
+
+    counts = lambda: np.array([TRACE_COUNTS[k] for k in (
+        "permuted_view", "fused_sweep", "tracking_sweep")])
+    before = counts()
+    row_major = run()
+    views, *traces = counts() - before
+    assert views == 0 and min(traces) > 0
+    forced_order(order)
+    before = counts()
+    permuted = run()
+    views, *traces = counts() - before
+    assert min(traces) > 0 and views == sum(traces)  # one leaf per trace
+    for a, b in zip(permuted, row_major):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    xr, nur = _ref_rows_f32(x, y, nu, params, "scad")
+    yr = jax.jit(lambda a, b, c, p: a + p[:, 4].reshape(
+        (2,) + (1,) * (a.ndim - 1)) * (b - c))(y, x, nu, params)
+    live = (np.ones((2, C)) if mask is None else np.asarray(mask)) > 0
+    live = live.reshape(live.shape + (1,) * len(shape))
+    want = (np.where(live, xr, x), np.where(live, nur, nu),
+            np.where(live, yr, y), np.where(live, x, nu))
+    for a, b in zip(permuted, want):
+        np.testing.assert_array_equal(np.asarray(a), b)
+
+
 def test_sweep_layout_tiles():
     """Leaves are viewed in place as (R, K) — K the leaf's own last dim —
     and tiled by full dims or aligned blocks; nothing is padded."""
