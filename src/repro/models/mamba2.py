@@ -79,32 +79,33 @@ def ssd_chunked(x, dt, A, b, c, chunk: int):
     nc = L // chunk
     f32 = jnp.float32
 
-    xc = x.reshape(B_, nc, chunk, H, P)
     dtc = dt.reshape(B_, nc, chunk, H).astype(f32)
     bc = b.reshape(B_, nc, chunk, N).astype(f32)
     cc = c.reshape(B_, nc, chunk, N).astype(f32)
+    # dt weights the input at s, so it scales x once, not every score
+    xdt = x.reshape(B_, nc, chunk, H, P).astype(f32) * dtc[..., None]
 
     la = dtc * A.astype(f32)                         # log-decay per step
-    cs = jnp.cumsum(la, axis=2)                      # inclusive cumsum (B,nc,Q,H)
+    tri = jnp.tril(jnp.ones((chunk, chunk), bool))
+    # inclusive cumsum as a matmul with the lower-triangular ones, so neither
+    # pass has a windowed reduction; HIGHEST keeps it f32, since the
+    # log-decays reach the hundreds over a chunk
+    cs = jnp.einsum("bksh,ts->bkht", la, tri.astype(f32),
+                    precision=jax.lax.Precision.HIGHEST)    # (B,nc,H,Q)
 
     # ---- intra-chunk (quadratic within chunk) ----
-    seg = cs[:, :, :, None, :] - cs[:, :, None, :, :]        # (B,nc,t,s,H)
-    tri = jnp.tril(jnp.ones((chunk, chunk), bool))
+    seg = cs[..., :, None] - cs[..., None, :]                 # (B,nc,H,t,s)
     # mask *before* exp so no inf enters the graph (NaN-safe gradients)
-    seg = jnp.where(tri[None, None, :, :, None], seg, -jnp.inf)
-    decay = jnp.exp(seg)
+    seg = jnp.where(tri, seg, -jnp.inf)
     cb = jnp.einsum("bktn,bksn->bkts", cc, bc)               # (B,nc,t,s)
-    scores = cb[:, :, :, :, None] * decay * dtc[:, :, None, :, :]  # dt at s
-    y_intra = jnp.einsum(
-        "bktsh,bkshp->bkthp", scores, xc.astype(f32)
-    )
+    scores = cb[:, :, None] * jnp.exp(seg)                   # (B,nc,H,t,s)
+    y_intra = jnp.einsum("bkhts,bkshp->bkthp", scores, xdt)
 
     # ---- chunk boundary states ----
-    rem = jnp.exp(cs[:, :, -1:, :] - cs)                     # decay to chunk end
-    wgt = (dtc * rem)                                        # (B,nc,Q,H)
-    Sk = jnp.einsum("bksn,bksh,bkshp->bkhnp", bc, wgt, xc.astype(f32))
+    rem = jnp.exp(cs[..., -1:] - cs)                         # decay to chunk end
+    Sk = jnp.einsum("bksn,bkhs,bkshp->bkhnp", bc, rem, xdt)
 
-    chunk_decay = jnp.exp(cs[:, :, -1, :])                   # (B,nc,H)
+    chunk_decay = jnp.exp(cs[..., -1])                       # (B,nc,H)
 
     def scan_fn(h_prev, inp):
         cd, sk = inp                                          # (B,H), (B,H,N,P)
@@ -120,7 +121,8 @@ def ssd_chunked(x, dt, A, b, c, chunk: int):
     h_prevs = jnp.moveaxis(h_prevs, 0, 1)                     # (B,nc,H,N,P)
 
     # ---- inter-chunk contribution ----
-    c_dec = cc[:, :, :, None, :] * jnp.exp(cs)[..., None]     # (B,nc,t,H,N)
+    decay_in = jnp.exp(jnp.swapaxes(cs, 2, 3))                # (B,nc,t,H)
+    c_dec = cc[:, :, :, None, :] * decay_in[..., None]        # (B,nc,t,H,N)
     y_inter = jnp.einsum("bkthn,bkhnp->bkthp", c_dec, h_prevs)
 
     y = (y_intra + y_inter).reshape(B_, L, H, P).astype(x.dtype)

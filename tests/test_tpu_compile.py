@@ -1,13 +1,15 @@
-"""Compile the fused update kernels for a described TPU v5e chip.
+"""Compile the fused update kernels and the Mamba-2 block for a described
+TPU v5e chip.
 
-Nothing runs: each test lowers a sweep-major kernel at a leaf shape of the
-full mamba2-130m state (4 clients, bf16) with Mosaic, compiles it for one
-chip of a described ``v5e:2x2`` topology, and checks that the program holds
-the Mosaic kernel (``tpu_custom_call``).  This catches what interpret mode
-cannot: block shapes the TPU's tiling refuses, VMEM overruns, layouts its
-compiler stalls on (a flat (S, C, d) view of the 50 280 x 768 embedding
+Nothing runs: each kernel test lowers a sweep-major kernel at a leaf shape
+of the full mamba2-130m state (4 clients, bf16) with Mosaic, compiles it for
+one chip of a described ``v5e:2x2`` topology, and checks that the program
+holds the Mosaic kernel (``tpu_custom_call``).  This catches what interpret
+mode cannot: block shapes the TPU's tiling refuses, VMEM overruns, layouts
+its compiler stalls on (a flat (S, C, d) view of the 50 280 x 768 embedding
 did not finish compiling in minutes), and relayout copies around the
 kernel where its view of a leaf disagrees with the chip's default layout.
+The block's test reads what the TPU compiler made of the SSD scan.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
@@ -23,6 +25,7 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.experimental.layout import Layout
 
 import repro.kernels
+from repro.configs import get_config
 from repro.kernels.prox.kernel import (
     TRACE_COUNTS,
     ClientShards,
@@ -30,6 +33,8 @@ from repro.kernels.prox.kernel import (
     fused_update_sweep_pallas,
 )
 from repro.kernels.prox.ops import fused_local_update, fused_tracking
+from repro.models.common import Initializer, split_params
+from repro.models.mamba2 import init_mamba, mamba_block
 
 C = 4
 # per-client leaf shapes of mamba2-130m (24 layers stacked); the embedding
@@ -255,3 +260,31 @@ def test_round_call_reads_default_layout(topo, one_chip, mosaic, kernel,
     for fmt, spec in zip(list(compiled.input_formats[0]) + outs,
                          args + (x,) * len(outs)):
         assert fmt.layout == _default_layout(dev, spec), spec
+
+
+def test_mamba_block_grad_has_no_windowed_reduction(one_chip):
+    """The gradient of one mamba2-130m block over two 256-token chunks: the
+    SSD's cumulative log-decay is a matmul with the triangular ones, at
+    HIGHEST precision in the forward and in its transpose, and neither pass
+    holds a ``reduce-window`` (what ``cumsum`` lowers to on the TPU)."""
+    cfg = get_config("mamba2-130m")
+    init = lambda k: split_params(
+        init_mamba(Initializer(k, jnp.bfloat16), cfg))[0]
+    params = jax.tree_util.tree_map(
+        lambda s: _spec(s.shape, s.dtype, one_chip),
+        jax.eval_shape(init, jax.random.key(0)))
+    u = _spec((1, 2 * cfg.ssm_chunk, cfg.d_model), jnp.bfloat16, one_chip)
+
+    def loss(p, u):
+        out, _ = mamba_block(p, u, cfg)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1)), (params, u))
+    assert "reduce-window" not in text
+    cumsum = [line for line in text.splitlines()
+              if re.search(r" (?:convolution|dot)\(", line)
+              and "bksh,ts->bkht" in line]
+    assert any("transpose(" in line for line in cumsum), cumsum
+    assert any("transpose(" not in line for line in cumsum), cumsum
+    for line in cumsum:
+        assert "operand_precision={highest,highest}" in line, line
