@@ -21,7 +21,6 @@ from repro.core import (
     MixPlan,
     init as dep_init,
     local_then_comm_round,
-    make_dense_mixer,
     mixing_matrix,
     plan_spectral_lambda,
     spectral_lambda,
@@ -151,7 +150,7 @@ def run_depositum(cfg: ExperimentConfig, collect_metrics: bool = True,
     grad_fns = {"local_at": jax.jit(local_at), "global_at": jax.jit(global_at)}
 
     W = mixing_matrix(cfg.topology, cfg.n_clients)
-    mixer = make_dense_mixer(W)
+    mixer = MixPlan.dense(W)
     dep = cfg.depositum
     state = dep_init(params0, cfg.n_clients)
     rnd = jax.jit(functools.partial(local_then_comm_round, grad_fn=grad_fn,

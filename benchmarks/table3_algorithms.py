@@ -27,7 +27,6 @@ from repro.core import (
     MixPlan,
     init as dep_init,
     local_then_comm_round,
-    make_dense_mixer,
     mixing_matrix,
     stack_hypers,
 )
@@ -141,7 +140,7 @@ def run_one(alg: str, theta: float, seed: int) -> float:
         state = dep_init(params0, N_CLIENTS)
         rnd = jax.jit(functools.partial(local_then_comm_round,
                                         grad_fn=grad_fn, config=dep,
-                                        mixer=make_dense_mixer(W)))
+                                        mixer=MixPlan.dense(W)))
         for r in range(ROUNDS):
             state, _ = rnd(state, batches=jax.tree_util.tree_map(
                 lambda b: b[r], batches))

@@ -12,9 +12,9 @@ import numpy as np
 
 from repro.core import (
     DepositumConfig,
+    MixPlan,
     init,
     local_then_comm_round,
-    make_dense_mixer,
     mixing_matrix,
 )
 from repro.data import make_classification
@@ -56,7 +56,7 @@ def main():
         state = init(jnp.zeros((d, classes)), n)
         rnd = jax.jit(functools.partial(local_then_comm_round,
                                         grad_fn=grad_fn, config=cfg,
-                                        mixer=make_dense_mixer(W)))
+                                        mixer=MixPlan.dense(W)))
         rng = np.random.default_rng(0)
         for _ in range(80):
             bx, by = ds.stacked_batches(rng, 32, cfg.comm_period)

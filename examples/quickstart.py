@@ -11,9 +11,9 @@ import numpy as np
 
 from repro.core import (
     DepositumConfig,
+    MixPlan,
     init,
     local_then_comm_round,
-    make_dense_mixer,
     mixing_matrix,
     stationarity_metrics,
 )
@@ -45,7 +45,7 @@ def main():
     W = mixing_matrix("ring", n_clients)
     state = init(jnp.zeros((d, n_classes)), n_clients)
     rnd = jax.jit(functools.partial(local_then_comm_round, grad_fn=grad_fn,
-                                    config=cfg, mixer=make_dense_mixer(W)))
+                                    config=cfg, mixer=MixPlan.dense(W)))
 
     xs = jnp.asarray(np.stack([ds.client_arrays(i)[0] for i in range(n_clients)]))
     ys = jnp.asarray(np.stack([ds.client_arrays(i)[1] for i in range(n_clients)]))

@@ -24,19 +24,11 @@ from repro.core.topology import (  # noqa: F401
     validate_mixing,
     delta_coefficients,
 )
-from repro.core.gossip import (  # noqa: F401
-    make_dense_mixer,
-    make_complete_mixer,
-    make_neighbor_mixer,
-    ring_mixer,
-    torus_mixer,
-    identity_mixer,
-)
+from repro.core.gossip import identity_mixer  # noqa: F401
 from repro.core.mixing import (  # noqa: F401
     MixPlan,
     apply_mix,
     as_dense,
-    as_mixer,
     plan_spectral_lambda,
     stack_mixplans,
     validate_plan,
@@ -75,6 +67,7 @@ from repro.core.schedule import (  # noqa: F401
     apply_schedule,
     as_schedule,
     as_stacked_schedule,
+    round_mixer,
     schedule_round_mask,
     schedule_spectral_lambda,
     stack_schedules,

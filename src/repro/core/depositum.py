@@ -43,15 +43,9 @@ from repro.core.compression import (
     comm_memory,
     comm_round_keys,
 )
-from repro.core.gossip import Mixer, identity_mixer
+from repro.core.gossip import identity_mixer
 from repro.core.hyper import Hyper
-from repro.core.mixing import resolve_mixer
-from repro.core.schedule import (
-    MixSchedule,
-    ScheduleMixer,
-    apply_schedule,
-    schedule_round_mask,
-)
+from repro.core.schedule import round_mixer, schedule_round_mask
 from repro.core.momentum import MomentumKind, momentum_update
 from repro.obs.trace import annotate
 from repro.core.prox import (
@@ -256,7 +250,7 @@ def step(
     batch: Any,
     grad_fn: GradFn,
     config: DepositumConfig,
-    mixer: Mixer,
+    mixer: Any,
     *,
     is_comm_step: jnp.ndarray | bool | None = None,
     hyper: Hyper | None = None,
@@ -278,14 +272,14 @@ def step(
     validated at the sweep boundary (``sweep_run`` / ``local_then_comm_round``)
     to keep traced/stacked values off the per-step hot path.
 
-    ``mixer`` may be a legacy ``Mixer`` closure, a
-    :class:`repro.core.mixing.MixPlan` (W as a traced operand, sweepable
-    over stacked topologies — see ``repro.training.sweep``), a
-    :class:`repro.core.schedule.MixSchedule`, or a backend-built
-    :class:`~repro.core.schedule.ScheduleMixer`.  For the round-indexed
-    forms the round this iteration belongs to is ``t // T0`` — derived from
-    the state's iteration counter, so schedules ride through ``lax.scan``
-    with no carry change.
+    ``mixer`` is a :class:`repro.core.mixing.MixPlan` (run as a constant
+    schedule; :data:`~repro.core.gossip.identity_mixer` is the identity
+    plan), a :class:`repro.core.schedule.MixSchedule`, or a backend-built
+    :class:`~repro.core.schedule.ScheduleMixer`; :func:`~repro.core.
+    schedule.round_mixer` turns the first two into the third.  The round
+    this iteration belongs to is ``t // T0`` — derived from the state's
+    iteration counter, so schedules ride through ``lax.scan`` with no
+    carry change.
 
     ``active_mask`` is the cohort gate: an (n,) 0/1 mask under which rows
     with mask 0 are *frozen* — every state variable keeps its previous
@@ -298,41 +292,27 @@ def step(
     fused kernels follow (the shard_map backend's mixers carry it as
     ``mixer.client_shards``, the default); None on one device.
     """
-    is_cohort_mixer = False
-    comm_spec = None       # active CompressionSpec of this round's schedule
+    sm = round_mixer(mixer)
+    shards = client_shards if client_shards is not None else sm.client_shards
+    is_cohort_mixer = sm.schedule.kind == "cohort"
+    r = state.t // config.comm_period
+    if active_mask is None:
+        active_mask = schedule_round_mask(sm, r)
+    comm_spec = active_compression(sm)  # this round's active spec, or None
+    mixer = _scoped("gossip", lambda tree: sm(tree, r))
     qmix = None            # how the compressed increment q communicates
     key_x = key_y = None
-    shards = (client_shards if client_shards is not None
-              else getattr(mixer, "client_shards", None))
-    if isinstance(mixer, (MixSchedule, ScheduleMixer)):
-        is_cohort_mixer = getattr(mixer, "schedule", mixer).kind == "cohort"
-        r = state.t // config.comm_period
-        if active_mask is None:
-            active_mask = schedule_round_mask(mixer, r)
-        comm_spec = active_compression(mixer)
-        wire_fn = getattr(mixer, "wire_fn", None)
-        if isinstance(mixer, MixSchedule):
-            sched = mixer
-            mixer = lambda tree: apply_schedule(sched, r, tree)
-        else:
-            sm = mixer
-            mixer = lambda tree: sm(tree, r)
-        if comm_spec is not None:
-            if not state.comm:
-                raise ValueError(
-                    "the schedule carries an active CompressionSpec but the "
-                    "state has no error-feedback memory; build it with "
-                    "init(..., compress=spec)")
-            # packed payloads on the wire when the backend supports it,
-            # else q rides the same collective the dense variable would
-            qmix = ((lambda tree: wire_fn(tree, r))
-                    if wire_fn is not None else mixer)
-            key_x, key_y = comm_round_keys(comm_spec, r)
-    else:
-        mixer, _plan = resolve_mixer(mixer)
-    mixer = _scoped("gossip", mixer)
-    if qmix is not None:
-        qmix = _scoped("gossip", qmix)
+    if comm_spec is not None:
+        if not state.comm:
+            raise ValueError(
+                "the schedule carries an active CompressionSpec but the "
+                "state has no error-feedback memory; build it with "
+                "init(..., compress=spec)")
+        # packed payloads on the wire when the backend supports it, else q
+        # rides the same collective the dense variable would
+        qmix = (mixer if sm.wire_fn is None else
+                _scoped("gossip", lambda tree: sm.wire_fn(tree, r)))
+        key_x, key_y = comm_round_keys(comm_spec, r)
     if hyper is None:
         config.validate()
         hp = config.hyper()
@@ -499,7 +479,7 @@ def local_then_comm_round(
     batches: Any,
     grad_fn: GradFn,
     config: DepositumConfig,
-    mixer: Mixer,
+    mixer: Any,
     *,
     hyper: Hyper | None = None,
     active_mask: jnp.ndarray | None = None,
@@ -511,10 +491,10 @@ def local_then_comm_round(
     identity mixer, so no collective appears inside the scan body; the final
     step applies the real mixer.  This is the production-shaped loop.
 
-    ``mixer`` accepts everything :func:`step` does — in particular a
-    round-indexed :class:`~repro.core.schedule.MixSchedule` (or a backend's
-    ``ScheduleMixer``), whose per-round plan is selected by the comm step
-    from ``t // T0``.
+    ``mixer`` accepts everything :func:`step` does: a plan, a
+    :class:`~repro.core.schedule.MixSchedule` or a backend's
+    ``ScheduleMixer``, whose per-round plan the comm step selects from
+    ``t // T0``.
 
     For a ``cohort`` schedule the round's active mask is drawn **once**
     here (``r`` is constant within a round) and threaded through every
@@ -524,9 +504,10 @@ def local_then_comm_round(
     T0 = config.comm_period
     if hyper is not None:
         config.validate(hyper)  # once per round; no-op for traced values
+    mixer = round_mixer(mixer)
     if active_mask is None:
         active_mask = schedule_round_mask(mixer, state.t // T0)
-    shards = getattr(mixer, "client_shards", None)
+    shards = mixer.client_shards
 
     def local_body(carry, batch):
         new_state, aux = step(

@@ -34,6 +34,7 @@ import numpy as np
 
 from repro.core.compression import _topk_rows
 from repro.core.mixing import MixPlan, apply_mix
+from repro.core.schedule import MixSchedule, ScheduleMixer
 
 PyTree = jax.Array
 
@@ -48,6 +49,10 @@ def make_trimmed_mean_mixer(W: np.ndarray, trim: int = 1):
     For client i: gather {x_j : w_ij > 0} (incl. itself), drop the ``trim``
     largest and smallest values per coordinate, average the rest.  Requires
     every neighborhood to have > 2*trim members.
+
+    Returns a :class:`~repro.core.schedule.ScheduleMixer` ``mix(tree, r)``
+    (the same every round) whose schedule is the constant plan of W: it
+    exchanges over W's edges, which is what its byte accounting reads.
     """
     adj = np.asarray(W) > 0
     np.fill_diagonal(adj, True)
@@ -57,7 +62,7 @@ def make_trimmed_mean_mixer(W: np.ndarray, trim: int = 1):
                          f"{int(counts.min()) - 1} neighborhoods")
     adj_j = jnp.asarray(adj)
 
-    def mix(tree):
+    def mix(tree, r):
         def leaf(x):
             n = x.shape[0]
             flat = x.reshape(n, -1)
@@ -80,7 +85,7 @@ def make_trimmed_mean_mixer(W: np.ndarray, trim: int = 1):
 
         return jax.tree_util.tree_map(leaf, tree)
 
-    return mix
+    return ScheduleMixer(mix, MixSchedule.constant(MixPlan.dense(W)))
 
 
 # ---------------------------------------------------------------------------

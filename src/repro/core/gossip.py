@@ -1,102 +1,26 @@
-"""Gossip mixing x <- (W ⊗ I) x over the client axis.
+"""Circulant gossip specs and the no-exchange operand.
 
-Client variables are pytrees whose leaves carry a leading ``clients`` dim
-(simulation: a plain stacked array; distributed: that dim is sharded over the
-mesh ``data``/``pod`` axes).
-
-Three strategies:
-
-* :func:`make_dense_mixer` — paper-faithful general path: contract the stacked
-  states with the dense mixing matrix W.  Under GSPMD this lowers to an
-  all-gather over the client axis (O(n·|theta|) bytes) + local contraction.
-* :func:`make_neighbor_mixer` — topology-aware path for *sparse* W inside
-  ``shard_map``: one ``lax.ppermute`` per neighbor offset (ring: 2, torus: 4),
-  O(deg·|theta|/n per client) bytes, network-size independent.  This is the
-  TPU-native adaptation of the paper's sparse gossip (DESIGN.md §3).
-* :func:`make_complete_mixer` — W = J: a single ``lax.pmean``.
-
-All mixers share the signature ``mix(tree) -> tree`` and are linear, doubly
-stochastic by construction, so the tracking identity J y = beta J g survives.
+Clients mix through one operand, :class:`~repro.core.schedule.MixSchedule`
+(a :class:`~repro.core.mixing.MixPlan` is its constant case).  What is left
+here is the circulant bookkeeping the tests cross-check plans against
+(:func:`torus_circulant_spec`, :func:`circulant_from_mixer_spec`) and
+:data:`identity_mixer`, the identity plan that the round program's
+collective-free local steps pass.
 """
 from __future__ import annotations
 
-from typing import Callable, Sequence
+from typing import Sequence
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-Mixer = Callable[[object], object]
+from repro.core.mixing import MixPlan
 
-
-def identity_mixer(tree):
-    return tree
-
-
-def make_dense_mixer(W) -> Mixer:
-    """x_i <- sum_j W_ij x_j via einsum on the leading client dim."""
-    Wj = jnp.asarray(W)
-
-    def mix(tree):
-        def leaf(x):
-            return jnp.einsum(
-                "ij,j...->i...", Wj.astype(x.dtype), x, precision=jax.lax.Precision.HIGHEST
-            )
-
-        return jax.tree_util.tree_map(leaf, tree)
-
-    return mix
-
-
-def make_complete_mixer(axis_name: str | tuple[str, ...]) -> Mixer:
-    """W = J inside shard_map/pmap: one all-reduce mean over the client axis."""
-
-    def mix(tree):
-        return jax.tree_util.tree_map(lambda x: jax.lax.pmean(x, axis_name), tree)
-
-    return mix
-
-
-def make_neighbor_mixer(
-    axis_name: str,
-    offsets_weights: Sequence[tuple[int, float]],
-    self_weight: float,
-    n: int,
-) -> Mixer:
-    """Sparse circulant gossip inside shard_map via lax.ppermute.
-
-    ``offsets_weights``: [(offset, weight)] — each client receives neighbor
-    ``(i + offset) mod n`` with that weight (circulant W rows).  For a
-    Metropolis ring of n>=3: offsets (+1, 1/3), (-1, 1/3), self 1/3.
-    ``n`` is the named-axis size (ppermute permutations are static, so it
-    cannot be inferred inside a trace portably).
-    """
-    perms = [
-        [((s + off) % n, s) for s in range(n)] for off, _ in offsets_weights
-    ]
-
-    def mix(tree):
-        def leaf(x):
-            out = self_weight * x
-            for (off, w), perm in zip(offsets_weights, perms):
-                out = out + w * jax.lax.ppermute(x, axis_name, perm)
-            return out
-
-        return jax.tree_util.tree_map(leaf, tree)
-
-    return mix
-
-
-def ring_mixer(axis_name: str, n: int) -> Mixer:
-    """Metropolis ring weights as a neighbor mixer (n >= 3)."""
-    if n < 3:
-        return make_complete_mixer(axis_name)
-    return make_neighbor_mixer(axis_name, [(+1, 1.0 / 3), (-1, 1.0 / 3)],
-                               1.0 / 3, n)
+#: W = I: "no exchange" as a mixing operand.
+identity_mixer = MixPlan.identity()
 
 
 def torus_grid_shape(n: int) -> tuple[int, int]:
-    """The near-square a×b factorisation shared by torus_graph/torus_mixer."""
+    """The near-square a×b grid of ``torus_graph`` and the circulant torus."""
     a = int(np.floor(np.sqrt(n)))
     while n % a != 0:
         a -= 1
@@ -113,12 +37,12 @@ def torus_circulant_spec(n: int):
     Both are symmetric doubly stochastic (Assumption 2 holds for either),
     both are degree-4 wrap-around graphs with comparable spectral lambda,
     but they are different graphs whenever b < n — including every
-    *square* grid.  The circulant is the form that maps onto ``ppermute``
-    (a fixed offset per collective), which is why the distributed path uses
-    it; cross-backend equivalence tests must therefore compare the neighbor
-    mixer against ``circulant_from_mixer_spec``/this spec's dense W, never
-    against ``torus_graph``.  On n = 2b the ±b offsets coincide and the
-    shared edge absorbs both weights (still symmetric, doubly stochastic).
+    *square* grid.  The circulant is the form that maps onto ``ppermute``:
+    ``MixPlan.circulant(*spec)`` runs one per offset, so cross-backend
+    equivalence tests must compare that plan against
+    ``circulant_from_mixer_spec``/this spec's dense W, never against
+    ``torus_graph``.  On n = 2b the ±b offsets coincide and the shared
+    edge absorbs both weights (still symmetric, doubly stochastic).
     Returns the ring spec when the factorisation degenerates (a < 2).
     """
     a, b = torus_grid_shape(n)
@@ -129,24 +53,12 @@ def torus_circulant_spec(n: int):
     return [(+1, 0.2), (-1, 0.2), (+b, 0.2), (-b, 0.2)], 0.2
 
 
-def torus_mixer(axis_name: str, n: int) -> Mixer:
-    """Circulant-torus gossip: 4 ppermutes at offsets ±1, ±b (b = n // a).
-
-    Exactly equal to the dense W of :func:`torus_circulant_spec` (tests
-    cross-check square and non-square n); an *approximation* of
-    ``topology.torus_graph``'s Metropolis grid — see the spec's docstring
-    for why the two graphs differ and when that matters.
-    """
-    offsets_weights, self_weight = torus_circulant_spec(n)
-    if offsets_weights is None:
-        return make_complete_mixer(axis_name)
-    return make_neighbor_mixer(axis_name, offsets_weights, self_weight, n)
-
-
 def circulant_from_mixer_spec(
     n: int, offsets_weights: Sequence[tuple[int, float]], self_weight: float
 ) -> np.ndarray:
-    """Dense W equal to a neighbor mixer — used to cross-check the two paths."""
+    """Dense W of the circulant plan ``MixPlan.circulant(offsets_weights,
+    self_weight)`` on n clients — the numpy reference plans are checked
+    against."""
     W = np.zeros((n, n))
     for i in range(n):
         W[i, i] += self_weight

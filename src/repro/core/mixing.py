@@ -33,8 +33,9 @@ split per backend (``repro.training.backends``):
 * :func:`shard_body` — per-shard semantics for a named mesh axis, to be
   called inside ``shard_map`` (ppermute / pmean / all_gather+contract).
 
-Both agree numerically with the legacy closures in ``repro.core.gossip``
-(tests cross-check them), which remain as thin adapters.
+The round program does not take a plan directly: it wraps it as a
+constant :class:`~repro.core.schedule.MixSchedule`, whose execution is
+exactly these two functions.
 """
 from __future__ import annotations
 
@@ -366,22 +367,6 @@ def apply_mix(plan: MixPlan, tree: PyTree) -> PyTree:
         return out
 
     return tm(leaf, tree)
-
-
-def as_mixer(plan: MixPlan):
-    """Legacy ``Mixer`` adapter: ``mix(tree) -> tree`` closure over the plan."""
-    return lambda tree: apply_mix(plan, tree)
-
-
-def resolve_mixer(mixer_or_plan) -> tuple[Any, Optional[MixPlan]]:
-    """Normalise a Mixer-or-MixPlan argument to ``(mixer_callable, plan)``.
-
-    ``plan`` is None for legacy closures — callers that need a sweepable
-    operand (stacked topologies) must pass a MixPlan.
-    """
-    if isinstance(mixer_or_plan, MixPlan):
-        return as_mixer(mixer_or_plan), mixer_or_plan
-    return mixer_or_plan, None
 
 
 # ---------------------------------------------------------------------------

@@ -9,8 +9,9 @@ related DFL literature turns (Liu et al.'s cost balancing, DFedAvg's
 multi-gossip).  A :class:`MixSchedule` promotes the communication pattern
 to a *round-indexed* operand that is scanned alongside the batches:
 
-* ``constant``    — one plan for every round.  Executes exactly the ops of
-  the static-plan path (bit-exact with PR 2 trajectories).
+* ``constant``    — one plan for every round: the form every plan takes in
+  the round program.  Executes exactly the plan's own ops
+  (``apply_mix`` / ``shard_body``).
 * ``stacked``     — plan leaves carry a leading round axis ``(R, ...)``;
   round ``r`` uses ``plan[r]`` (clamped at R-1 past the end).
 * ``lazy(p, rng)``— Remark 3 partial participation: a per-round 0/1
@@ -160,7 +161,7 @@ class MixSchedule:
     # -- constructors -------------------------------------------------------
     @classmethod
     def constant(cls, plan: MixPlan) -> "MixSchedule":
-        """The PR 2 static-plan behaviour as a schedule (bit-exact)."""
+        """``plan`` on every round: exactly the plan's own ops."""
         if plan.is_stacked:
             raise ValueError(
                 "constant schedules take an unstacked plan; use "
@@ -408,15 +409,8 @@ def _lazy_dense_matrix(W: jnp.ndarray, a: jnp.ndarray) -> jnp.ndarray:
 def _apply_lazy(plan: MixPlan, a: jnp.ndarray, tree: PyTree) -> PyTree:
     """One lazy round on stacked clients: dense masked contraction or
     per-offset masked rolls for circulant bases."""
-    tm = jax.tree_util.tree_map
     if plan.kind == "dense":
-        Wt = _lazy_dense_matrix(plan.W, a)
-
-        def leaf(x):
-            return jnp.einsum("ij,j...->i...", Wt.astype(x.dtype), x,
-                              precision=jax.lax.Precision.HIGHEST)
-
-        return tm(leaf, tree)
+        return apply_mix(MixPlan.dense(_lazy_dense_matrix(plan.W, a)), tree)
     # circulant: out_i = x_i + sum_k w_k a_i a_{i+off_k} (x_{i+off_k} - x_i)
     ws = plan.weights
 
@@ -429,7 +423,7 @@ def _apply_lazy(plan: MixPlan, a: jnp.ndarray, tree: PyTree) -> PyTree:
                 jnp.roll(x, -off, axis=0) - x)
         return out
 
-    return tm(leaf, tree)
+    return jax.tree_util.tree_map(leaf, tree)
 
 
 def apply_schedule(sched: MixSchedule, r, tree: PyTree) -> PyTree:
@@ -474,23 +468,24 @@ def schedule_round_mask(mixer_or_sched, r) -> Optional[jnp.ndarray]:
     return None
 
 
-def as_schedule(mixer_or_plan) -> "MixSchedule":
+def as_schedule(plan_or_schedule) -> "MixSchedule":
     """Normalise a plan to a constant schedule (identity on schedules)."""
-    if isinstance(mixer_or_plan, MixSchedule):
-        return mixer_or_plan
-    if isinstance(mixer_or_plan, MixPlan):
-        return MixSchedule.constant(mixer_or_plan)
+    if isinstance(plan_or_schedule, MixSchedule):
+        return plan_or_schedule
+    if isinstance(plan_or_schedule, MixPlan):
+        return MixSchedule.constant(plan_or_schedule)
     raise TypeError(f"cannot build a MixSchedule from "
-                    f"{type(mixer_or_plan).__name__}")
+                    f"{type(plan_or_schedule).__name__}")
 
 
 @dataclasses.dataclass(frozen=True)
 class ScheduleMixer:
     """A round-indexed mixer: ``mix(tree, r) -> tree``.
 
-    Built by the execution backends; the round program recognises it and
-    supplies ``r = t // T0`` from the iteration counter.  (A plain Mixer
-    closure stays ``mix(tree) -> tree``.)
+    The one form the round program mixes with: :func:`round_mixer` builds
+    it from a plan or a schedule (an execution backend's ``mixer_for``
+    builds it for its placement), and ``repro.core.depositum.step``
+    supplies ``r = t // T0`` from the iteration counter.
 
     ``wire_fn`` — when the schedule carries a packable
     :class:`~repro.core.compression.CompressionSpec` — is the backend's
@@ -513,6 +508,24 @@ class ScheduleMixer:
 
     def __call__(self, tree: PyTree, r) -> PyTree:
         return self.fn(tree, r)
+
+
+def round_mixer(operand, backend=None) -> ScheduleMixer:
+    """The round program's one mixing operand, from any of its forms.
+
+    ``operand`` is a :class:`MixPlan` (the identity plan included), which
+    runs as ``MixSchedule.constant(plan)``; a :class:`MixSchedule`; or a
+    :class:`ScheduleMixer`, returned as it is.  ``backend`` (an execution
+    backend, ``repro.training.backends``) places the mix; None mixes the
+    stacked client dim with :func:`apply_schedule`.
+    """
+    if isinstance(operand, ScheduleMixer):
+        return operand
+    sched = as_schedule(operand)
+    if backend is not None:
+        return backend.mixer_for(sched)
+    return ScheduleMixer(lambda tree, r: apply_schedule(sched, r, tree),
+                         sched)
 
 
 # ---------------------------------------------------------------------------

@@ -23,8 +23,9 @@ import numpy as np  # noqa: E402
 from repro.analysis.hlo import parse_collectives  # noqa: E402
 from repro.analysis.roofline import model_flops, roofline_terms  # noqa: E402
 from repro.configs.base import INPUT_SHAPES, get_config  # noqa: E402
-from repro.core import DepositumConfig  # noqa: E402
+from repro.core import DepositumConfig, MixPlan  # noqa: E402
 from repro.core.depositum import DepositumState  # noqa: E402
+from repro.launch.gossip_dist import make_shardmap_mixer  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.sharding import (  # noqa: E402
     make_placement,
@@ -147,12 +148,16 @@ def _lower_combo(cfg, arch, shape_name, mesh, *, mixer_kind="dense",
             prox_name="l1", prox_kwargs={"lam": 1e-6},
         )
         if mixer_kind == "dense":
-            step = build_train_step(model, dep_cfg, n, topology=topology,
-                                    microbatch=microbatch)
+            mixer = MixPlan.from_topology(topology, n)
         else:
-            step = build_train_step(
-                model, dep_cfg, n, microbatch=microbatch,
-                mixer=_shardmap_mixer(placement, st_axes, st_shapes, topology))
+            # ring/complete lower to ppermute/pmean, the rest to all_gather
+            # + per-shard rows; the mixer sees one state variable (x or y)
+            # at a time, so its specs are the param-level tree
+            mixer = make_shardmap_mixer(
+                placement, st_axes.x, st_shapes.x,
+                MixPlan.from_topology(topology, n, prefer="sparse"))
+        step = build_train_step(model, dep_cfg, n, mixer,
+                                microbatch=microbatch)
         jitted = jax.jit(step, in_shardings=(st_sh, b_sh),
                          out_shardings=(st_sh, None), donate_argnums=(0,))
         return jitted.lower(st_shapes, b_shapes)
@@ -280,21 +285,6 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, *,
                         ("t_compute_s", "t_memory_s", "t_collective_s",
                          "dominant")})
     return result
-
-
-def _shardmap_mixer(placement, st_axes, st_shapes, topology):
-    """Topology-aware shard_map mixer (beyond-paper optimisation; §Perf).
-
-    Any named topology works: ring/complete lower to ppermute/pmean, the
-    rest to an exact dense plan (all_gather + per-shard row contraction) —
-    all via the shared ``MixPlan`` dispatch in ``repro.core.mixing``.  The
-    mixer is applied to one state *component* (x or y) at a time, so the
-    spec tree is the param-level tree (with the leading clients dim).
-    """
-    from repro.launch.gossip_dist import make_shardmap_mixer, plan_for_topology
-
-    plan = plan_for_topology(topology, placement.n_clients)
-    return make_shardmap_mixer(placement, st_axes.x, st_shapes.x, plan)
 
 
 def main():

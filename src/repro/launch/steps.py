@@ -9,19 +9,19 @@
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Callable
+from typing import TYPE_CHECKING
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig
 from repro.core import DepositumConfig, identity_mixer
 from repro.core.depositum import step as depositum_step
 from repro.core.mixing import MixPlan
-from repro.core.schedule import MixSchedule
+from repro.core.schedule import round_mixer
 from repro.models.registry import Model
-from repro.training.backends import ExecutionBackend, StackedVmapBackend
+
+if TYPE_CHECKING:  # repro.training imports this module
+    from repro.training.backends import ExecutionBackend
 
 
 def make_grad_fn(model: Model, microbatch: int = 1):
@@ -93,32 +93,27 @@ def build_train_step(
     model: Model,
     dep_cfg: DepositumConfig,
     n_clients: int,
-    topology: str = "ring",
     mixer=None,
+    *,
     microbatch: int = 1,
-    plan: MixPlan | None = None,
     backend: ExecutionBackend | None = None,
-    schedule: MixSchedule | None = None,
 ):
     """(state, batch) -> (state, aux); batch leaves (n, B, ...).
 
-    Mixing resolves in priority order: an explicit ``mixer`` closure (e.g. a
-    placement-aware shard_map mixer from ``launch.gossip_dist`` — including
-    its round-indexed ``ScheduleMixer``), else a round-indexed ``schedule``
-    (:class:`~repro.core.schedule.MixSchedule`), else a
-    ``plan``/``topology`` — executed by ``backend`` (default stacked-vmap:
-    dense contraction, which GSPMD lowers to all-gather + local einsum on a
-    sharded client axis).  Schedules derive their round from the state's
-    iteration counter (``t // T0``) inside ``depositum.step`` — including
-    ``cohort`` schedules, whose per-round active mask both gates the mix
-    and freezes inactive/padding rows of the (padded) client axis.
+    ``mixer`` is the one mixing operand: a plan, a
+    :class:`~repro.core.schedule.MixSchedule`, or a ``ScheduleMixer`` (such
+    as the placement-aware shard_map mixer of ``launch.gossip_dist``);
+    default the Metropolis ring plan.  A plan or schedule is executed by
+    ``backend`` (default stacked-vmap: dense contraction, which GSPMD
+    lowers to all-gather + local einsum on a sharded client axis).
+    Schedules derive their round from the state's iteration counter
+    (``t // T0``) inside ``depositum.step`` — including ``cohort``
+    schedules, whose per-round active mask both gates the mix and freezes
+    inactive/padding rows of the (padded) client axis.
     """
     if mixer is None:
-        operand = schedule
-        if operand is None:
-            operand = (plan if plan is not None
-                       else MixPlan.from_topology(topology, n_clients))
-        mixer = (backend or StackedVmapBackend()).mixer_for(operand)
+        mixer = MixPlan.from_topology("ring", n_clients)
+    mixer = round_mixer(mixer, backend)
     grad_fn = make_grad_fn(model, microbatch=microbatch)
 
     def train_step(state, batch):

@@ -217,7 +217,7 @@ def traced_round_bytes(sched, r, d: int, *,
     if isinstance(sched, MixPlan):
         sched = MixSchedule.constant(sched)
     if not isinstance(sched, MixSchedule):
-        # legacy Mixer closures carry no plan structure to account
+        # no plan or schedule: no structure to account
         return jnp.float32(float("nan"))
     plan = sched.plan
     base = plan.base_plan() if plan.kind == "chebyshev" else plan

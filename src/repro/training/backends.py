@@ -1,7 +1,8 @@
 """Execution backends: one round program, three ways to run it.
 
-A backend answers exactly one question — *how does a* :class:`MixPlan`
-*execute on this placement* — so the DEPOSITUM round program
+A backend answers exactly one question — *how does a*
+:class:`~repro.core.schedule.MixSchedule` *execute on this placement* —
+so the DEPOSITUM round program
 (``local_then_comm_round``), the sweep engine, the launchers, and the
 fedopt baselines can all share it:
 
@@ -19,9 +20,11 @@ fedopt baselines can all share it:
   axis over the distributed path).
 
 ``get_backend("stacked-vmap" | "shard_map" | "sweep", ...)`` builds one by
-name.  All backends expose ``mixer_for(plan) -> Mixer``; plans with traced
-leaves must be threaded as operands (the sweep engine does this), never
-baked into a jit closure, or the one-program-per-grid guarantee is lost.
+name.  All backends expose ``mixer_for(operand) -> ScheduleMixer``: the
+operand is a schedule or a plan, and a plan runs as the constant schedule
+``MixSchedule.constant(plan)``.  Operands with traced leaves must be
+threaded as operands (the sweep engine does this), never baked into a jit
+closure, or the one-program-per-grid guarantee is lost.
 
 Fused local compute: the *mixing* strategy above is orthogonal to the
 local-update kernel.  With ``config.use_fused_kernel`` the round program's
@@ -38,24 +41,22 @@ out.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Optional, Protocol, runtime_checkable
+from typing import Any, Optional, Protocol, runtime_checkable
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-from repro.core.mixing import MixPlan, as_mixer, shard_body
 from repro.core.schedule import (
     MixSchedule,
     ScheduleMixer,
-    apply_schedule,
+    as_schedule,
+    round_mixer,
     shard_compressed_qmix,
     shard_schedule_body,
     wire_supported,
 )
 from repro.kernels.prox.kernel import ClientShards
-
-Mixer = Callable[[Any], Any]
 
 
 def _plan_kind(plan_or_schedule) -> str:
@@ -80,7 +81,7 @@ class ExecutionBackend(Protocol):
     #: consults this before honouring ``fused="require"``.
     supports_fused_sweep: bool
 
-    def mixer_for(self, plan: MixPlan) -> Mixer:  # pragma: no cover
+    def mixer_for(self, operand) -> ScheduleMixer:  # pragma: no cover
         ...
 
     def place(self, state: Any) -> Any:  # pragma: no cover
@@ -93,19 +94,16 @@ class ExecutionBackend(Protocol):
 class StackedVmapBackend:
     """Simulation semantics: leading client dim, jnp-only mixing.
 
-    ``mixer_for`` accepts a :class:`MixPlan` (returns a plain Mixer) or a
-    round-indexed :class:`MixSchedule` (returns a ``ScheduleMixer`` —
-    ``mix(tree, r)`` — which the round program drives from ``t // T0``).
+    ``mixer_for`` takes a plan or a schedule and returns a
+    ``ScheduleMixer`` — ``mix(tree, r)``, which the round program drives
+    from ``t // T0`` — over :func:`~repro.core.schedule.apply_schedule`.
     """
 
     name: str = dataclasses.field(default="stacked-vmap", init=False)
     supports_fused_sweep: bool = dataclasses.field(default=True, init=False)
 
-    def mixer_for(self, plan) -> Mixer:
-        if isinstance(plan, MixSchedule):
-            return ScheduleMixer(
-                lambda tree, r: apply_schedule(plan, r, tree), plan)
-        return as_mixer(plan)
+    def mixer_for(self, operand) -> ScheduleMixer:
+        return round_mixer(as_schedule(operand))
 
     def place(self, state):
         return state
@@ -163,34 +161,11 @@ class ShardMapBackend:
 
         return jax.tree_util.tree_map(put, state)
 
-    def mixer_for(self, plan) -> Mixer:
-        if isinstance(plan, MixSchedule):
-            return self._schedule_mixer(plan)
-        if plan.kind == "identity":
-            def mix(tree):
-                return tree
-        else:
-            size, _n = self._check_plan(plan)
-            spec_axis = self.axis_name
-
-            def mix(tree):
-                def leaf(x):
-                    spec = P(spec_axis)
-                    fn = jax.shard_map(
-                        lambda blk: shard_body(plan, blk, spec_axis, size),
-                        mesh=self.mesh, in_specs=(spec,), out_specs=spec,
-                    )
-                    return fn(x)
-
-                return jax.tree_util.tree_map(leaf, tree)
-
-        mix.client_shards = self.client_shards  # read by depositum.step
-        return mix
-
-    def _schedule_mixer(self, sched: MixSchedule) -> Mixer:
-        """Round-indexed mixer: per-round ``shard_body`` variants (masked
-        ppermute/all_gather for lazy rounds, unrolled collectives for
-        chebyshev) inside one ``shard_map`` per leaf.
+    def mixer_for(self, operand) -> ScheduleMixer:
+        """Round-indexed mixer of a plan or schedule: per-round
+        ``shard_body`` variants (masked ppermute/all_gather for lazy
+        rounds, unrolled collectives for chebyshev) inside one
+        ``shard_map`` per leaf.
 
         When the schedule carries a packable
         :class:`~repro.core.compression.CompressionSpec`, the returned
@@ -199,40 +174,23 @@ class ShardMapBackend:
         ``shard_compressed_qmix``) instead of dense-shaped, so the CHOCO
         exchange in ``depositum.step`` actually shrinks bytes on the wire.
         """
+        sched = as_schedule(operand)
         size, _n = self._check_plan(sched)
-        spec_axis = self.axis_name
+        spec = P(self.axis_name)
 
-        def mix(tree, r):
-            rr = jnp.asarray(r, jnp.int32)
-
-            def leaf(x):
-                spec = P(spec_axis)
-                fn = jax.shard_map(
-                    lambda blk: shard_schedule_body(sched, rr, blk,
-                                                    spec_axis, size),
-                    mesh=self.mesh, in_specs=(spec,), out_specs=spec,
-                )
-                return fn(x)
-
-            return jax.tree_util.tree_map(leaf, tree)
-
-        wire = None
-        if wire_supported(sched):
-            def wire(tree, r):
+        def placed(body):
+            """``body(sched, r, blk, axis, size)`` per leaf in shard_map."""
+            def run(tree, r):
                 rr = jnp.asarray(r, jnp.int32)
+                return jax.tree_util.tree_map(lambda x: jax.shard_map(
+                    lambda blk: body(sched, rr, blk, self.axis_name, size),
+                    mesh=self.mesh, in_specs=(spec,), out_specs=spec)(x),
+                    tree)
 
-                def leaf(x):
-                    spec = P(spec_axis)
-                    fn = jax.shard_map(
-                        lambda blk: shard_compressed_qmix(sched, rr, blk,
-                                                          spec_axis, size),
-                        mesh=self.mesh, in_specs=(spec,), out_specs=spec,
-                    )
-                    return fn(x)
+            return run
 
-                return jax.tree_util.tree_map(leaf, tree)
-
-        return ScheduleMixer(mix, sched, wire_fn=wire,
+        wire = placed(shard_compressed_qmix) if wire_supported(sched) else None
+        return ScheduleMixer(placed(shard_schedule_body), sched, wire_fn=wire,
                              client_shards=self.client_shards)
 
 
@@ -254,8 +212,8 @@ class SweepBackend:
     def supports_fused_sweep(self) -> bool:
         return getattr(self.inner, "supports_fused_sweep", True)
 
-    def mixer_for(self, plan: MixPlan) -> Mixer:
-        return self.inner.mixer_for(plan)
+    def mixer_for(self, operand) -> ScheduleMixer:
+        return self.inner.mixer_for(operand)
 
     def place(self, state):
         return self.inner.place(state)

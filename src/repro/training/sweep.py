@@ -14,9 +14,9 @@ the sweep axis, composed with the per-client ``vmap`` inside ``grad_fn``.
 
 Shapes:
   hypers        Hyper with leaves (S,)            (or unstacked: broadcast)
-  mixer         Mixer closure, or MixPlan whose leaves may carry a leading
-                (S,) axis (dense: W is (S, n, n)) — the topology sweep
-                axis — or a round-indexed MixSchedule whose leaves may
+  mixer         MixPlan whose leaves may carry a leading (S,) axis
+                (dense: W is (S, n, n)) — the topology sweep axis — or
+                a round-indexed MixSchedule whose leaves may
                 carry the same leading (S,) sweep axis ahead of their
                 round axis (stacked: W is (S, R, n, n); lazy: active is
                 (S, R, n)) — the *schedule* sweep axis
@@ -75,7 +75,6 @@ from repro.training.backends import (
 PyTree = Any
 GradFn = Callable[[PyTree, Any], tuple[PyTree, Any]]
 MetricsFn = Callable[[DepositumState, Hyper], dict]
-Mixer = Callable[[PyTree], PyTree]
 
 
 # ---------------------------------------------------------------------------
@@ -103,7 +102,7 @@ def stack_rounds(batch_list: Iterable[PyTree]) -> PyTree:
 
 
 # ---------------------------------------------------------------------------
-# Sweep-operand plumbing: (mixer | MixPlan) + Hyper -> vmap axes
+# Sweep-operand plumbing: (MixPlan | MixSchedule) + Hyper -> vmap axes
 # ---------------------------------------------------------------------------
 
 def _mapped_len(tree, axis: Optional[int]) -> int:
@@ -120,26 +119,21 @@ def _take(tree, s: int, axis: Optional[int]):
     return jax.tree_util.tree_map(lambda v: jnp.take(v, s, axis=axis), tree)
 
 
-def _normalise_operands(mixer, hypers, n_extra: int = 1
-                        ) -> tuple[Optional[Mixer], MixPlan,
-                                   Hyper, int, Any, Any]:
-    """Returns (legacy_mixer, plan, hypers, S, hyper_axes, plan_axes).
+def _normalise_operands(plan, hypers, n_extra: int = 1
+                        ) -> tuple[Hyper, int, Any, Any]:
+    """Returns (hypers, S, hyper_axes, plan_axes).
 
-    Exactly one of ``legacy_mixer`` / a real plan is active: legacy Mixer
-    closures ride along untouched (plan degenerates to identity with no
-    leaves), MixPlans — and round-indexed MixSchedules, which expose the
-    same ``is_stacked``/``n_sweep``/``point`` surface over their *sweep*
-    axis — become traced operands.  Unstacked operands broadcast (in_axes
-    None); stacked ones map (in_axes 0) and must agree on S.  ``n_extra``
-    is the sweep length implied by other mapped operands (params_axis /
+    MixPlans — and round-indexed MixSchedules, which expose the same
+    ``is_stacked``/``n_sweep``/``point`` surface over their *sweep* axis —
+    are traced operands.  Unstacked operands broadcast (in_axes None);
+    stacked ones map (in_axes 0) and must agree on S.  ``n_extra`` is the
+    sweep length implied by other mapped operands (params_axis /
     batch_axis), so params-only or data-only sweeps with an unstacked
     Hyper/plan still size S correctly.
     """
-    if isinstance(mixer, (MixPlan, MixSchedule)):
-        legacy, plan = None, mixer
-    else:
-        legacy, plan = mixer, MixPlan.identity()
-
+    if not isinstance(plan, (MixPlan, MixSchedule)):
+        raise TypeError("sweeps mix by a MixPlan or MixSchedule operand, "
+                        f"got {type(plan).__name__}")
     S_h = n_sweep(hypers)
     hyper_stacked = jnp.ndim(hypers.alpha) > 0
     S_p = plan.n_sweep
@@ -158,7 +152,7 @@ def _normalise_operands(mixer, hypers, n_extra: int = 1
         hyper_stacked = True
     hyper_axes = 0 if hyper_stacked else None
     plan_axes = 0 if plan.is_stacked else None
-    return legacy, plan, hypers, S, hyper_axes, plan_axes
+    return hypers, S, hyper_axes, plan_axes
 
 
 def _validate_operand(plan, n_clients: int) -> None:
@@ -222,9 +216,9 @@ def _scanned_run(grad_fn, config, n_clients, metrics_fn, mixer_factory,
     """One sweep point's whole run as a scan over rounds:
     (hyper, plan, params, batches) -> (final_state, per_round_outputs).
     Shared by the vmapped and the serial paths so their computations cannot
-    drift apart.  ``mixer_factory(plan) -> Mixer`` is the backend's
-    execution strategy; the plan arrives as a traced operand, never baked
-    in.
+    drift apart.  ``mixer_factory(plan) -> ScheduleMixer`` is the
+    backend's execution strategy; the plan arrives as a traced operand,
+    never baked in.
 
     With a :class:`~repro.obs.record.Telemetry` attached the returned
     runner takes two extra operands ``(tag, log_every)``: the recorder's
@@ -310,7 +304,7 @@ def make_sweep_round(
     Returns ``round_fn(states, hypers, batches, plan=None) -> (states, aux)``
     where ``states`` leaves carry a leading sweep dim.  Use this for
     streaming loops that cannot pre-stack all rounds of data.  ``mixer``
-    may be a Mixer closure or a (possibly stacked) MixPlan / MixSchedule.
+    is a (possibly stacked) MixPlan / MixSchedule.
 
     The resolved plan is threaded as a **runtime operand** of the jitted
     round — per the operand contract in ``repro.training.backends``, its
@@ -328,21 +322,18 @@ def make_sweep_round(
     """
     backend = backend or StackedVmapBackend()
     _check_fused_boundary(config, backend=backend)
-    legacy, plan0, _, _, _, plan_axes = _normalise_operands(
-        mixer, Hyper.create())
-    mixer_factory = ((lambda p: legacy) if legacy is not None
-                     else backend.mixer_for)
+    _, _, _, plan_axes = _normalise_operands(mixer, Hyper.create())
 
     def one(state, hyper, plan, batches):
         return local_then_comm_round(
-            state, batches, grad_fn, config, mixer_factory(plan), hyper=hyper
-        )
+            state, batches, grad_fn, config, backend.mixer_for(plan),
+            hyper=hyper)
 
     vm = jax.vmap(one, in_axes=(0, 0, plan_axes, batch_axis))
     jitted = jax.jit(vm)
 
     def round_fn(states, hypers, batches, plan=None):
-        plan_arg = plan0 if plan is None else plan
+        plan_arg = mixer if plan is None else plan
         # broadcast an unstacked Hyper over the sweep axis (sweep_run's
         # documented behaviour; states always carry the sweep dim)
         if jnp.ndim(hypers.alpha) == 0:
@@ -372,8 +363,7 @@ def sweep_run(
 ) -> tuple[DepositumState, dict]:
     """Run ``rounds`` federated rounds for every sweep point at once.
 
-    ``mixer``: a legacy Mixer closure (topology fixed for the whole sweep)
-    or a :class:`MixPlan`; a *stacked* plan (dense W of shape (S, n, n))
+    ``mixer``: a :class:`MixPlan` or :class:`MixSchedule`; a *stacked* plan (dense W of shape (S, n, n))
     makes the topology itself a sweep dimension, zipped with the Hyper axis.
     ``batches`` leaves: (rounds, T0, n_clients, B, ...) — shared across the
     sweep (``batch_axis=None``, the common fair-comparison case) or with an
@@ -399,14 +389,12 @@ def sweep_run(
     _check_fused_boundary(config, params0, backend)
     n_extra = max(_mapped_len(params0, params_axis),
                   _mapped_len(batches, batch_axis))
-    legacy, plan, hypers, S, hyper_axes, plan_axes = _normalise_operands(
-        mixer, hypers, n_extra)
-    if legacy is None:
-        _validate_operand(plan, n_clients)
-    mixer_factory = ((lambda p: legacy) if legacy is not None
-                     else backend.mixer_for)
+    plan = mixer
+    hypers, S, hyper_axes, plan_axes = _normalise_operands(
+        plan, hypers, n_extra)
+    _validate_operand(plan, n_clients)
     run_one = _scanned_run(grad_fn, config, n_clients, metrics_fn,
-                           mixer_factory, telemetry)
+                           backend.mixer_for, telemetry)
     if telemetry is None:
         runner = jax.jit(jax.vmap(
             run_one,
@@ -450,17 +438,15 @@ def sweep_run_sequential(
     _check_fused_boundary(config, params0, backend)
     n_extra = max(_mapped_len(params0, params_axis),
                   _mapped_len(batches, batch_axis))
-    legacy, plan, hypers, S, hyper_axes, plan_axes = _normalise_operands(
-        mixer, hypers, n_extra)
-    if legacy is None:
-        _validate_operand(plan, n_clients)  # same legality gate as sweep_run
-    mixer_factory = ((lambda p: legacy) if legacy is not None
-                     else backend.mixer_for)
+    plan = mixer
+    hypers, S, hyper_axes, plan_axes = _normalise_operands(
+        plan, hypers, n_extra)
+    _validate_operand(plan, n_clients)  # same legality gate as sweep_run
     # the *same* scanned program as sweep_run — only the batching differs —
     # so the equivalence the tests assert is between vmap and a serial loop,
     # never between two drifting copies of the round logic
     run_one = jax.jit(_scanned_run(grad_fn, config, n_clients,
-                                   metrics_fn, mixer_factory, telemetry))
+                                   metrics_fn, backend.mixer_for, telemetry))
 
     results = []
     for s in range(S):
@@ -524,8 +510,9 @@ def sweep_run_fedalg(
         _validate_operand(plan, n_clients)
     n_extra = max(_mapped_len(params0, params_axis),
                   _mapped_len(batches, batch_axis))
-    _, plan_arg, hypers, S, hyper_axes, plan_axes = _normalise_operands(
-        plan if plan is not None else MixPlan.identity(), hypers, n_extra)
+    plan_arg = plan if plan is not None else MixPlan.identity()
+    hypers, S, hyper_axes, plan_axes = _normalise_operands(
+        plan_arg, hypers, n_extra)
     metrics = _metrics_caller(metrics_fn)
 
     def run_one(hyper, plan_s, params, batches):

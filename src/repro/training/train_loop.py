@@ -44,11 +44,11 @@ class TrainerConfig:
 class FederatedTrainer:
     """Drives DEPOSITUM rounds for a zoo model on stacked client batches.
 
-    Mixing resolves in priority order: an explicit ``mixer`` closure, else a
-    round-indexed ``schedule`` (:class:`~repro.core.schedule.MixSchedule` —
-    time-varying topologies, partial participation, per-round ``cohort``
-    sampling over a padded client axis, Chebyshev rounds), else a static
-    plan built from ``cfg.topology``.  For a ``cohort`` schedule
+    Clients mix by ``schedule`` (:class:`~repro.core.schedule.MixSchedule`
+    — time-varying topologies, partial participation, per-round ``cohort``
+    sampling over a padded client axis, Chebyshev rounds), else by the
+    plan of ``cfg.topology``, which runs as a constant schedule; the
+    backend builds the one mixer from it.  For a ``cohort`` schedule
     ``cfg.n_clients`` is the *padded* axis length ``n_max`` (the round
     program freezes inactive and padding rows).  With ``backend=None`` the
     execution backend is auto-selected from the plan's sparsity and the
@@ -57,7 +57,7 @@ class FederatedTrainer:
     hosts get the matching shard_map collective schedule.
     """
 
-    def __init__(self, model: Model, cfg: TrainerConfig, mixer=None,
+    def __init__(self, model: Model, cfg: TrainerConfig,
                  backend: ExecutionBackend | None = None,
                  schedule: MixSchedule | None = None,
                  telemetry: Telemetry | bool | None = None):
@@ -81,8 +81,7 @@ class FederatedTrainer:
         self._mix_operand = operand
         backend = backend or suggest_backend(operand, cfg.n_clients)
         self.backend = backend
-        self.mixer = (mixer if mixer is not None
-                      else backend.mixer_for(operand))
+        self.mixer = backend.mixer_for(operand)
 
         # shared with AsyncTrainer (same gradient program ⇒ the async τ=0
         # sync-equivalence pin compares trajectories bit for bit)

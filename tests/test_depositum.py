@@ -11,7 +11,7 @@ from repro.core import (
     DepositumConfig,
     init,
     local_then_comm_round,
-    make_dense_mixer,
+    MixPlan,
     mixing_matrix,
     stationarity_metrics,
     step,
@@ -39,7 +39,7 @@ def quadratic_problem(n=10, d=8, seed=0):
 
 def run_rounds(cfg, n, grad_fn, rounds, topology="ring", d=8, seed=0):
     W = mixing_matrix(topology, n)
-    mixer = make_dense_mixer(W)
+    mixer = MixPlan.dense(W)
     state = init(jnp.zeros(d), n)
     rnd = jax.jit(functools.partial(
         local_then_comm_round, grad_fn=grad_fn, config=cfg, mixer=mixer
@@ -69,7 +69,7 @@ def test_tracking_invariant(beta, gamma, pattern, momentum):
                           momentum=momentum, comm_period=3,
                           prox_name="l1", prox_kwargs={"lam": 1e-3})
     W = mixing_matrix("ring", n)
-    mixer = make_dense_mixer(W)
+    mixer = MixPlan.dense(W)
     state = init(jnp.zeros(d), n)
     for comm in pattern:
         state, _ = step(state, None, grad_fn, cfg,
@@ -124,7 +124,7 @@ def test_centralized_proximal_gd_equivalence():
                           comm_period=1, prox_name="l1",
                           prox_kwargs={"lam": lam})
     W = mixing_matrix("complete", n)
-    mixer = make_dense_mixer(W)
+    mixer = MixPlan.dense(W)
     state = init(jnp.zeros(d), n)
 
     from repro.core.prox import make_l1

@@ -32,7 +32,7 @@ def test_ppermute_gossip_equals_dense_mix():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.core.gossip import make_dense_mixer
+        from repro.core.mixing import MixPlan, apply_mix
         from repro.core.topology import mixing_matrix
 
         mesh = jax.make_mesh((8,), ("data",))
@@ -42,7 +42,7 @@ def test_ppermute_gossip_equals_dense_mix():
         xs = jax.device_put(x, NamedSharding(mesh, P("data")))
 
         W = mixing_matrix("ring", n)
-        dense = jax.jit(lambda t: make_dense_mixer(W)(t))(xs)
+        dense = jax.jit(lambda t: apply_mix(MixPlan.dense(W), t))(xs)
 
         def body(blk):
             perm_f = [((s + 1) % n, s) for s in range(n)]
@@ -65,8 +65,8 @@ def test_depositum_distributed_equals_host():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.core import (DepositumConfig, init, step,
-                                make_dense_mixer, mixing_matrix)
+        from repro.core import (DepositumConfig, MixPlan, init, step,
+                                mixing_matrix)
 
         n, d = 8, 32
         key = jax.random.PRNGKey(0)
@@ -78,7 +78,7 @@ def test_depositum_distributed_equals_host():
         cfg = DepositumConfig(alpha=0.05, beta=1.0, gamma=0.5, comm_period=1,
                               prox_name="l1", prox_kwargs={"lam": 1e-3})
         W = mixing_matrix("ring", n)
-        mixer = make_dense_mixer(W)
+        mixer = MixPlan.dense(W)
 
         st_host = init(jnp.zeros(d), n)
         for _ in range(5):
@@ -159,16 +159,15 @@ def test_topology_sweep_shardmap_backend_equals_sequential():
 def test_placement_shardmap_mixer_all_topologies():
     """launch.gossip_dist executes any named topology exactly: ring/complete
     via ppermute/pmean, star/torus via the dense all_gather+contract plan —
-    all matching the dense einsum mixer on an 8-device host mesh."""
+    all matching the dense plan on an 8-device host mesh."""
     out = run_py(textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.sharding import Placement, _RULES_REPLICATED
-        from repro.launch.gossip_dist import (make_shardmap_mixer,
-                                              plan_for_topology)
-        from repro.core.gossip import make_dense_mixer
+        from repro.launch.gossip_dist import make_shardmap_mixer
+        from repro.core.mixing import MixPlan, apply_mix
         from repro.core.topology import mixing_matrix
 
         mesh = jax.make_mesh((8, 1), ("data", "model"))
@@ -182,10 +181,10 @@ def test_placement_shardmap_mixer_all_topologies():
         axes = ("clients", "mlp")
         shapes = jax.ShapeDtypeStruct((n, d), jnp.float32)
         for topo in ("ring", "complete", "star", "torus"):
-            plan = plan_for_topology(topo, n)
+            plan = MixPlan.from_topology(topo, n, prefer="sparse")
             mix = make_shardmap_mixer(placement, axes, shapes, plan)
-            got = jax.jit(mix)(xs)
-            ref = make_dense_mixer(mixing_matrix(topo, n))(x)
+            got = jax.jit(lambda t: mix(t, 0))(xs)
+            ref = apply_mix(MixPlan.dense(mixing_matrix(topo, n)), x)
             err = float(jnp.max(jnp.abs(got - ref)))
             assert err < 1e-5, (topo, err)
         print("OK")
@@ -513,7 +512,7 @@ def test_tiny_dryrun_mesh_compiles():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, numpy as np
         from repro.configs import get_config
-        from repro.core import DepositumConfig
+        from repro.core import DepositumConfig, MixPlan
         from repro.launch.sharding import Placement, _RULES_REPLICATED
         from repro.launch.dryrun import state_specs
         from repro.launch.specs import train_batch_specs
@@ -540,7 +539,8 @@ def test_tiny_dryrun_mesh_compiles():
         b_sh = tree_shardings(placement, b_axes, b_shapes)
         dep = DepositumConfig(alpha=1e-3, prox_name="l1",
                               prox_kwargs={"lam": 1e-6})
-        stepfn = build_train_step(model, dep, n, topology="ring")
+        stepfn = build_train_step(model, dep, n,
+                                  MixPlan.from_topology("ring", n))
         jitted = jax.jit(stepfn, in_shardings=(st_sh, b_sh),
                          out_shardings=(st_sh, None))
         compiled = jitted.lower(st_shapes, b_shapes).compile()

@@ -10,7 +10,7 @@ from hypothesis import given, settings, strategies as st
 from repro.core import (
     DepositumConfig,
     init,
-    make_dense_mixer,
+    MixPlan,
     mixing_matrix,
     spectral_lambda,
     step,
@@ -57,7 +57,7 @@ def test_chebyshev_preserves_tracking_invariant():
     cfg = DepositumConfig(alpha=0.05, beta=beta, gamma=0.5, comm_period=1,
                           prox_name="l1", prox_kwargs={"lam": 1e-3})
     state = init(jnp.zeros(d), n)
-    mixer = make_dense_mixer(P)
+    mixer = MixPlan.dense(P)
     for _ in range(6):
         state, _ = step(state, None, grad_fn, cfg, mixer, is_comm_step=True)
         np.testing.assert_allclose(

@@ -70,14 +70,14 @@ def test_depositum_beats_or_matches_baselines_iterationwise():
     objective is within/below the envelope of the baselines given the same
     rounds and step size."""
     from repro.core import (DepositumConfig, init as dep_init,
-                            local_then_comm_round, make_dense_mixer)
+                            local_then_comm_round, MixPlan)
 
     w0, batch, grad_fn, objective, n = setup_problem()
     W = mixing_matrix("ring", n)
     dep = DepositumConfig(alpha=0.1, beta=1.0, gamma=0.5, comm_period=5,
                           prox_name="l1", prox_kwargs={"lam": 1e-3})
     state = dep_init(w0, n)
-    mixer = make_dense_mixer(W)
+    mixer = MixPlan.dense(W)
     batches = jax.tree_util.tree_map(
         lambda v: jnp.broadcast_to(v[None], (5,) + v.shape), batch
     )

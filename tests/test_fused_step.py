@@ -8,7 +8,7 @@ import pytest
 from repro.core import (
     DepositumConfig,
     init,
-    make_dense_mixer,
+    MixPlan,
     mixing_matrix,
     step,
 )
@@ -29,7 +29,7 @@ def test_fused_step_matches_reference(prox, kwargs):
         return A * x - b, {}
 
     W = mixing_matrix("ring", n)
-    mixer = make_dense_mixer(W)
+    mixer = MixPlan.dense(W)
     out = {}
     for fused in (False, True):
         cfg = DepositumConfig(alpha=0.05, beta=1.0, gamma=0.8,
@@ -59,7 +59,7 @@ def test_fused_step_masked_generic_mixer_matches_reference():
     def grad_fn(x, batch):
         return A * x, {}
 
-    mixer = make_dense_mixer(mixing_matrix("ring", n))
+    mixer = MixPlan.dense(mixing_matrix("ring", n))
     mask = jnp.asarray([1, 0, 1, 1, 0, 1], jnp.float32)
     out = {}
     for fused in (False, True):
@@ -93,7 +93,7 @@ def test_fused_falls_back_for_nesterov():
         return A * x, {}
 
     W = mixing_matrix("complete", n)
-    mixer = make_dense_mixer(W)
+    mixer = MixPlan.dense(W)
     out = {}
     for fused in (False, True):
         cfg = DepositumConfig(alpha=0.05, gamma=0.5, momentum="nesterov",

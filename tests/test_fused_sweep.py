@@ -28,7 +28,6 @@ from repro.core import (
     MixSchedule,
     init as dep_init,
     local_then_comm_round,
-    make_dense_mixer,
     mixing_matrix,
     stack_hypers,
     step,
@@ -355,7 +354,7 @@ def _grid(scale=1.0):
 @pytest.mark.parametrize("prox", ["l1", "mcp", "scad"])
 def test_sweep_run_fused_matches_unfused(prox):
     grad_fn = linear_problem()
-    mixer = make_dense_mixer(mixing_matrix("ring", N))
+    mixer = MixPlan.dense(mixing_matrix("ring", N))
     hypers = _grid()
     batches = jnp.zeros((ROUNDS, T0, 1))
     out = {}
@@ -380,7 +379,7 @@ def test_stacked_grid_zero_retrace_across_configs():
     grid; feeding a NEW hyperparameter grid (same shapes) reuses it with
     zero fused-kernel retraces."""
     grad_fn = linear_problem()
-    mixer = make_dense_mixer(mixing_matrix("ring", N))
+    mixer = MixPlan.dense(mixing_matrix("ring", N))
     cfg = DepositumConfig(momentum="polyak", comm_period=T0,
                           prox_name="l1", prox_kwargs={"lam": 1e-3},
                           use_fused_kernel=True)
@@ -448,7 +447,7 @@ def _cfg(**kw):
 def _one_step(cfg, d=32, n=4, hyper=None):
     A = jax.random.normal(jax.random.PRNGKey(0), (n, d))
     st = dep_init(jnp.ones(d), n)
-    mixer = make_dense_mixer(mixing_matrix("complete", n))
+    mixer = MixPlan.dense(mixing_matrix("complete", n))
     return step(st, None, lambda x, b: (A * x, {}), cfg, mixer,
                 is_comm_step=True, hyper=hyper)
 
@@ -491,7 +490,7 @@ def test_fused_require_raises_for_stacked_hyper():
 
 def test_fused_require_raises_for_nonfloat_params_at_boundary():
     grad_fn = linear_problem()
-    mixer = make_dense_mixer(mixing_matrix("ring", N))
+    mixer = MixPlan.dense(mixing_matrix("ring", N))
     cfg = _cfg(fused="require", comm_period=T0)
     with pytest.raises(ValueError, match="non-float"):
         sweep_run(jnp.zeros(D, jnp.int32), grad_fn, cfg, mixer, _grid(),
@@ -508,7 +507,7 @@ def test_fused_require_raises_for_optout_backend():
             return StackedVmapBackend().mixer_for(plan)
 
     grad_fn = linear_problem()
-    mixer = make_dense_mixer(mixing_matrix("ring", N))
+    mixer = MixPlan.dense(mixing_matrix("ring", N))
     cfg = _cfg(fused="require", comm_period=T0)
     with pytest.raises(ValueError, match="opts out"):
         sweep_run(jnp.zeros(D), grad_fn, cfg, mixer, _grid(),
@@ -518,7 +517,7 @@ def test_fused_require_raises_for_optout_backend():
 
 def test_fused_require_happy_path_runs():
     grad_fn = linear_problem()
-    mixer = make_dense_mixer(mixing_matrix("ring", N))
+    mixer = MixPlan.dense(mixing_matrix("ring", N))
     cfg = _cfg(fused="require", comm_period=T0)
     fs, _ = sweep_run(jnp.zeros(D), grad_fn, cfg, mixer, _grid(),
                       jnp.zeros((ROUNDS, T0, 1)), n_clients=N)

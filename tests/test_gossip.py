@@ -1,13 +1,11 @@
-"""Gossip mixers: dense einsum vs circulant neighbor spec must agree."""
+"""Gossip: dense plans vs circulant neighbor specs must agree."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 from hypothesis import given, settings, strategies as st
 
-from repro.core.gossip import (
-    circulant_from_mixer_spec,
-    make_dense_mixer,
-)
+from repro.core.gossip import circulant_from_mixer_spec
+from repro.core.mixing import MixPlan, apply_mix
 from repro.core.topology import mixing_matrix, validate_mixing
 
 
@@ -16,7 +14,7 @@ from repro.core.topology import mixing_matrix, validate_mixing
 def test_dense_mixer_matches_matmul(n, d, seed):
     W = mixing_matrix("ring", n)
     x = np.random.default_rng(seed).standard_normal((n, d)).astype(np.float32)
-    out = make_dense_mixer(W)({"p": jnp.asarray(x)})["p"]
+    out = apply_mix(MixPlan.dense(W), {"p": jnp.asarray(x)})["p"]
     np.testing.assert_allclose(np.asarray(out), W @ x, rtol=1e-5, atol=1e-6)
 
 
@@ -36,7 +34,7 @@ def test_mixing_preserves_mean():
     W = mixing_matrix("ring", n)
     x = jnp.asarray(np.random.default_rng(0).standard_normal((n, d)),
                     jnp.float32)
-    out = make_dense_mixer(W)(x)
+    out = apply_mix(MixPlan.dense(W), x)
     np.testing.assert_allclose(np.asarray(jnp.mean(out, 0)),
                                np.asarray(jnp.mean(x, 0)), rtol=1e-5,
                                atol=1e-6)

@@ -1,8 +1,9 @@
 """MixPlan: mixing as a traced operand.
 
-Every plan kind must agree with the legacy closure mixers, stacked plans
-must vmap like stacked Hypers, and the torus circulant's documented
-divergence from the grid-graph Metropolis W must hold exactly as stated.
+Every plan kind must agree with the numpy product W @ x of its dense W, a
+plan must run exactly as its constant schedule, stacked plans must vmap
+like stacked Hypers, and the torus circulant's documented divergence from
+the grid-graph Metropolis W must hold exactly as stated.
 """
 import jax
 import jax.numpy as jnp
@@ -10,22 +11,22 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.core import DepositumConfig, init as dep_init, local_then_comm_round
 from repro.core.gossip import (
     circulant_from_mixer_spec,
-    make_dense_mixer,
     torus_circulant_spec,
     torus_grid_shape,
-    torus_mixer,
 )
 from repro.core.mixing import (
     MixPlan,
     apply_mix,
     as_dense,
-    as_mixer,
     plan_spectral_lambda,
+    shard_body,
     stack_mixplans,
     validate_plan,
 )
+from repro.core.schedule import MixSchedule
 from repro.core.topology import (
     mixing_matrix,
     spectral_lambda,
@@ -40,7 +41,7 @@ def _x(n, d, seed=0):
 
 
 # ---------------------------------------------------------------------------
-# kind-by-kind equivalence with the legacy closures
+# kind-by-kind equivalence with the numpy product W @ x
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("topology", ["ring", "star", "torus", "complete"])
@@ -49,9 +50,8 @@ def test_dense_plan_matches_dense_mixer(topology):
     W = mixing_matrix(topology, n)
     x = _x(n, d)
     got = apply_mix(MixPlan.dense(W), {"p": x})["p"]
-    ref = make_dense_mixer(W)({"p": x})["p"]
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=1e-6, atol=1e-7)
+    ref = W.astype(np.float32) @ np.asarray(x)
+    np.testing.assert_allclose(np.asarray(got), ref, rtol=1e-6, atol=1e-6)
 
 
 @settings(max_examples=15, deadline=None)
@@ -82,20 +82,73 @@ def test_complete_and_identity_plans():
                                np.eye(n), atol=1e-7)
 
 
-def test_as_mixer_adapter_and_resolve():
-    from repro.core.mixing import resolve_mixer
+_ROUND_PLANS = {
+    "dense": ("ring", lambda n: MixPlan.from_topology("ring", n)),
+    "circulant": ("ring",
+                  lambda n: MixPlan.from_topology("ring", n, prefer="sparse")),
+    "complete": ("complete",
+                 lambda n: MixPlan.from_topology("complete", n,
+                                                 prefer="sparse")),
+}
 
-    n, d = 5, 3
-    W = mixing_matrix("ring", n)
-    x = _x(n, d)
-    plan = MixPlan.dense(W)
-    np.testing.assert_allclose(np.asarray(as_mixer(plan)(x)),
-                               np.asarray(apply_mix(plan, x)))
-    mix, p = resolve_mixer(plan)
-    assert p is plan
-    legacy = make_dense_mixer(W)
-    mix2, p2 = resolve_mixer(legacy)
-    assert mix2 is legacy and p2 is None
+
+@pytest.mark.parametrize("T0", [1, 2])
+@pytest.mark.parametrize("kind", sorted(_ROUND_PLANS))
+def test_plan_runs_as_its_constant_schedule(kind, T0):
+    """A plan is its constant schedule: the plan, ``MixSchedule.constant``
+    of it and the backend's mixer of either give bit-identical rounds, and
+    a trainer built from a topology lowers to the same program as one
+    given that topology's constant schedule."""
+    from repro.training.backends import StackedVmapBackend
+    from repro.training.train_loop import FederatedTrainer, TrainerConfig
+
+    n, d = 5, 6
+    topology, make = _ROUND_PLANS[kind]
+    plan = make(n)
+    assert plan.kind == kind
+    sched = MixSchedule.constant(plan)
+    backend = StackedVmapBackend()
+    A = _x(n * d, d, seed=3).reshape(n, d, d)
+
+    def grad_fn(x, batch):
+        return jnp.einsum("nij,nj->ni", A, x) - batch, {}
+
+    cfg = DepositumConfig(alpha=0.05, beta=1.0, gamma=0.5,
+                          momentum="polyak", comm_period=T0,
+                          prox_name="l1", prox_kwargs={"lam": 1e-3})
+    batches = _x(T0 * n, d, seed=4).reshape(T0, n, d)
+    state0 = dep_init(jnp.zeros(d), n)
+    outs = [jax.jit(lambda s, b, m=m: local_then_comm_round(
+                s, b, grad_fn, cfg, m)[0])(state0, batches)
+            for m in (plan, sched, backend.mixer_for(plan),
+                      backend.mixer_for(sched))]
+    for out in outs[1:]:
+        for a, b in zip(jax.tree_util.tree_leaves(outs[0]),
+                        jax.tree_util.tree_leaves(out)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    Aw = np.asarray(A[0])
+
+    def init(key):
+        return {"w": jnp.zeros((d,))}, None
+
+    def loss(params, batch):
+        r = Aw @ params["w"] - batch
+        return jnp.mean(r * r), {}
+
+    from repro.models.registry import Model
+
+    model = Model(cfg=None, init=init, forward_train=None, loss=loss,
+                  forward_decode=None, init_decode_cache=None)
+    tc = TrainerConfig(n_clients=n, topology=topology, depositum=cfg)
+    by_topology = FederatedTrainer(model, tc)
+    by_schedule = FederatedTrainer(
+        model, tc,
+        schedule=MixSchedule.constant(MixPlan.from_topology(topology, n)))
+    state = jax.eval_shape(lambda: dep_init({"w": jnp.zeros((d,))}, n))
+    batch = jax.ShapeDtypeStruct((T0, n, d), jnp.float32)
+    assert (by_topology.lower_round(state, batch).as_text()
+            == by_schedule.lower_round(state, batch).as_text())
 
 
 # ---------------------------------------------------------------------------
@@ -168,16 +221,20 @@ def test_plan_is_jit_operand_no_retrace():
 
 @pytest.mark.parametrize("n", [6, 8, 10, 12, 15])
 def test_torus_mixer_equals_its_circulant_dense_W(n):
-    """The neighbor mixer must equal circulant_from_mixer_spec exactly —
-    including NON-square grids and the n == 2b coincident-offset case."""
+    """The circulant torus plan — one ppermute per offset over a named
+    axis, and the stacked rolls — must equal W @ x for
+    circulant_from_mixer_spec's W, including NON-square grids and the
+    n == 2b coincident-offset case."""
     offsets_weights, self_w = torus_circulant_spec(n)
+    plan = MixPlan.circulant(offsets_weights, self_w)
     d = 3
     x = _x(n, d, seed=n)
-    mixer = torus_mixer("c", n)
-    got = jax.vmap(lambda xi: mixer(xi), axis_name="c")(x)
     W = circulant_from_mixer_spec(n, offsets_weights, self_w)
-    np.testing.assert_allclose(np.asarray(got), W @ np.asarray(x),
-                               rtol=1e-5, atol=1e-6)
+    permuted = jax.vmap(lambda xi: shard_body(plan, xi, "c", n),
+                        axis_name="c")(x)
+    for got in (permuted, apply_mix(plan, x)):
+        np.testing.assert_allclose(np.asarray(got), W @ np.asarray(x),
+                                   rtol=1e-5, atol=1e-6)
     # the circulant W itself satisfies Assumption 2
     validate_mixing(W)
 

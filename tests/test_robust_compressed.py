@@ -8,8 +8,9 @@ import pytest
 
 from repro.core import (
     DepositumConfig,
+    MixPlan,
+    apply_mix,
     init,
-    make_dense_mixer,
     mixing_matrix,
     step,
 )
@@ -27,7 +28,7 @@ def test_trimmed_mean_equals_mean_without_outliers():
     x = jnp.asarray(np.random.default_rng(0).standard_normal((n, d)),
                     jnp.float32)
     mixer = make_trimmed_mean_mixer(W, trim=1)
-    out = mixer(x)
+    out = mixer(x, 0)
     # complete graph: trimmed mean of all clients per coordinate
     ref = []
     xs = np.sort(np.asarray(x), axis=0)
@@ -45,8 +46,8 @@ def test_trimmed_mean_survives_byzantine_client():
     x[3] = 1e6  # Byzantine
     xj = jnp.asarray(x)
 
-    robust = make_trimmed_mean_mixer(W, trim=1)(xj)
-    plain = make_dense_mixer(W)(xj)
+    robust = make_trimmed_mean_mixer(W, trim=1)(xj, 0)
+    plain = apply_mix(MixPlan.dense(W), xj)
     honest_mean = x[np.arange(n) != 3].mean(0)
     assert float(jnp.max(jnp.abs(robust[0] - honest_mean))) < 1.0
     assert float(jnp.max(jnp.abs(plain[0] - honest_mean))) > 1e4
@@ -83,7 +84,7 @@ def test_trimmed_mean_depositum_converges_under_attack():
             jnp.max(jnp.abs(xbar)))
 
     g_rob, mag_rob = run(make_trimmed_mean_mixer(W, trim=1))
-    g_pln, mag_pln = run(make_dense_mixer(W))
+    g_pln, mag_pln = run(MixPlan.dense(W))
     assert mag_rob < 10.0, mag_rob            # robust stays bounded
     assert mag_pln > 10.0 or g_pln > g_rob    # plain gets poisoned
     assert g_rob < 2.0, g_rob

@@ -14,7 +14,6 @@ from repro.core import (
     hyper_grid,
     init as dep_init,
     local_then_comm_round,
-    make_dense_mixer,
     mixing_matrix,
     n_sweep,
     stack_hypers,
@@ -67,7 +66,7 @@ def test_sweep_matches_sequential(momentum, prox_name):
                           prox_name=prox_name,
                           prox_kwargs={"lam": 1e-3, "theta": 4.0}
                           if prox_name == "mcp" else {"lam": 1e-3})
-    mixer = make_dense_mixer(mixing_matrix("ring", N))
+    mixer = MixPlan.dense(mixing_matrix("ring", N))
     hypers = stack_hypers([Hyper.create(**p, theta=4.0)
                            for p in _grid_points(prox_name)])
     batches = jnp.zeros((ROUNDS, T0, 1))
@@ -94,7 +93,7 @@ def test_sweep_matches_sequential(momentum, prox_name):
 def test_sweep_matches_classic_config_floats(momentum, prox_name):
     """Each sweep row == the pre-refactor path (floats baked into closures)."""
     grad_fn = linear_problem()
-    mixer = make_dense_mixer(mixing_matrix("ring", N))
+    mixer = MixPlan.dense(mixing_matrix("ring", N))
     points = _grid_points(prox_name)
     cfg0 = DepositumConfig(momentum=momentum, comm_period=T0,
                            prox_name=prox_name,
@@ -126,7 +125,7 @@ def test_sweep_matches_classic_config_floats(momentum, prox_name):
 def test_fused_kernel_sweep_matches_reference_sweep():
     """use_fused_kernel under the sweep vmap == unfused sweep (Polyak/l1)."""
     grad_fn = linear_problem()
-    mixer = make_dense_mixer(mixing_matrix("ring", N))
+    mixer = MixPlan.dense(mixing_matrix("ring", N))
     hypers = hyper_grid(alpha=[0.02, 0.1], lam=[1e-4, 5e-3])
     hypers = hypers.replace(gamma=jnp.full_like(hypers.alpha, 0.6))
     batches = jnp.zeros((ROUNDS, T0, 1))
@@ -151,7 +150,7 @@ def test_streaming_round_and_batch_adapters():
     grad_fn = linear_problem()
     cfg = DepositumConfig(momentum="polyak", comm_period=T0, prox_name="l1",
                           prox_kwargs={"lam": 1e-3})
-    mixer = make_dense_mixer(mixing_matrix("ring", N))
+    mixer = MixPlan.dense(mixing_matrix("ring", N))
     h = Hyper.create(alpha=0.05, beta=1.0, gamma=0.5, lam=1e-3)
     hypers = stack_hypers([h, h])  # identical points must stay identical
     assert n_sweep(hypers) == 2
@@ -228,7 +227,7 @@ def test_topology_sweep_matches_sequential_and_classic():
 
     # each sweep point == the pre-refactor closure-mixer run of its graph
     for s, topo in enumerate(TOPOS):
-        mixer = make_dense_mixer(mixing_matrix(topo, N))
+        mixer = MixPlan.dense(mixing_matrix(topo, N))
         state = dep_init(jnp.zeros(D), N)
         rnd = jax.jit(functools.partial(local_then_comm_round,
                                         grad_fn=grad_fn, config=cfg,
@@ -461,7 +460,7 @@ def test_stack_rounds_and_metrics_shapes():
     grad_fn = linear_problem()
     cfg = DepositumConfig(momentum="polyak", comm_period=T0, prox_name="l1",
                           prox_kwargs={"lam": 1e-3})
-    mixer = make_dense_mixer(mixing_matrix("ring", N))
+    mixer = MixPlan.dense(mixing_matrix("ring", N))
     # base= anchors unswept fields (lam here) at the config's actual values
     hypers = hyper_grid(base=cfg.hyper(), alpha=[0.02, 0.05, 0.1])
     assert abs(float(hypers.lam[0]) - 1e-3) < 1e-9

@@ -10,7 +10,7 @@ from repro.core import (
     DepositumConfig,
     Hyper,
     init as dep_init,
-    make_dense_mixer,
+    MixPlan,
     mixing_matrix,
     prox_apply,
     step,
@@ -111,7 +111,7 @@ def test_step_with_donated_state_and_traced_hyper():
 
     cfg = DepositumConfig(alpha=0.07, beta=0.9, gamma=0.4, comm_period=1,
                           prox_name="l1", prox_kwargs={"lam": 1e-3})
-    mixer = make_dense_mixer(mixing_matrix("ring", n))
+    mixer = MixPlan.dense(mixing_matrix("ring", n))
 
     stepped = jax.jit(
         lambda st, hyper: step(st, None, grad_fn, cfg, mixer,
@@ -143,7 +143,7 @@ def test_no_recompile_across_hyper_values():
 
     cfg = DepositumConfig(comm_period=1, prox_name="l1",
                           prox_kwargs={"lam": 1e-3})
-    mixer = make_dense_mixer(mixing_matrix("complete", n))
+    mixer = MixPlan.dense(mixing_matrix("complete", n))
     stepped = jax.jit(
         lambda st, hyper: step(st, None, grad_fn, cfg, mixer,
                                is_comm_step=True, hyper=hyper)[0]
@@ -169,7 +169,7 @@ def test_hyper_scalars_preserve_param_dtype(dtype):
     cfg = DepositumConfig(alpha=0.05, beta=1.0, gamma=0.6, momentum="nesterov",
                           comm_period=T0, prox_name="mcp",
                           prox_kwargs={"lam": 1e-3, "theta": 4.0})
-    mixer = make_dense_mixer(mixing_matrix("ring", n))
+    mixer = MixPlan.dense(mixing_matrix("ring", n))
     st = dep_init(jnp.ones(d, dtype), n)
     rnd = jax.jit(lambda st, hyper: local_then_comm_round(
         st, jnp.zeros((T0, 1)), grad_fn, cfg, mixer, hyper=hyper)[0])
