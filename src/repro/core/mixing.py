@@ -52,6 +52,7 @@ from repro.core.topology import (
     spectral_lambda,
     validate_mixing,
 )
+from repro.kernels.mix import dense_mix
 
 PyTree = Any
 
@@ -334,6 +335,9 @@ def apply_mix(plan: MixPlan, tree: PyTree) -> PyTree:
     """x_i <- sum_j W_ij x_j on the leading client dim of every leaf.
 
     Works under jit/vmap/scan with the plan's arrays as traced operands.
+    The dense path mixes each leaf with W in the leaf's dtype, on a TPU by
+    the Pallas mix kernel in the leaf's own layout
+    (:func:`repro.kernels.mix.dense_mix`).
     The circulant path uses ``jnp.roll`` per offset — out_i picks up
     x[(i + off) % n], matching both ``circulant_from_mixer_spec`` and the
     ppermute perm ``[((s + off) % n, s)]``.
@@ -350,13 +354,7 @@ def apply_mix(plan: MixPlan, tree: PyTree) -> PyTree:
                                                       keepdims=True),
                                              x.shape), tree)
     if plan.kind == "dense":
-        W = plan.W
-
-        def leaf(x):
-            return jnp.einsum("ij,j...->i...", W.astype(x.dtype), x,
-                              precision=jax.lax.Precision.HIGHEST)
-
-        return tm(leaf, tree)
+        return tm(lambda x: dense_mix(plan.W, x), tree)
     # circulant: out_i = self_w * x_i + sum_k w_k * x[(i + off_k) % n]
     sw, ws = plan.self_weight, plan.weights
 

@@ -1,5 +1,5 @@
-"""Pallas kernels of the round program (prox / fused update) and flash
-attention.  Both families run under Mosaic on a TPU and in the Pallas
+"""Pallas kernels of the round program (prox / fused update, the dense
+client mix) and flash attention.  Both families run under Mosaic on a TPU and in the Pallas
 interpreter on the CPU, and refuse any other backend."""
 import jax
 

@@ -51,7 +51,9 @@ MIN_BLOCK_ROWS = 32      # multiple of every float sublane tile (f32 8, bf16 16)
 # (leaves viewed in a non-row-major device layout, one per leaf and call).
 # Incremented inside the jitted wrappers, so a count rises only when XLA
 # actually (re)traces — the regression tests pin "zero retraces across
-# configs" with these.
+# configs" with these.  ``kernels/mix.py`` adds "mix" / "mix_fallback":
+# the dense-mixed leaves of each trace under Mosaic, by its kernel / by
+# the einsum.
 TRACE_COUNTS: collections.Counter = collections.Counter()
 
 
@@ -143,15 +145,17 @@ class SweepLayout(NamedTuple):
 
 
 @functools.lru_cache(maxsize=None)
-def sweep_layout(shape: tuple[int, ...]) -> SweepLayout:
-    """Block layout for a per-client leaf of ``shape``, computed once per
-    distinct shape (the fused path does no host-side shape arithmetic per
-    round)."""
+def sweep_layout(shape: tuple[int, ...],
+                 block_elems: int = BLOCK_ELEMS) -> SweepLayout:
+    """Block layout for a per-client leaf of ``shape``, about
+    ``block_elems`` elements per block, computed once per distinct shape
+    (the fused path does no host-side shape arithmetic per round)."""
     cols = shape[-1] if shape else 1
     rows = math.prod(shape[:-1])
     block_cols = min(cols, MAX_BLOCK_COLS)
     lanes = pl.cdiv(block_cols, LANE) * LANE
-    cap = max(MIN_BLOCK_ROWS, 1 << ((BLOCK_ELEMS // lanes).bit_length() - 1))
+    fit = max(1, block_elems // lanes)
+    cap = max(MIN_BLOCK_ROWS, 1 << (fit.bit_length() - 1))
     return SweepLayout(rows=rows, cols=cols,
                        block_rows=rows if rows <= cap else cap,
                        block_cols=block_cols)
@@ -232,21 +236,26 @@ def _tracking_sweep_kernel(p_ref, *refs, gated):
 def _default_order(shape: tuple[int, ...], dtype: np.dtype,
                    device) -> tuple[int, ...]:
     """Major-to-minor axis order of ``device``'s default layout for an
-    array of ``shape`` and ``dtype``, if it keeps axes 0 and 1 (S, C)
-    major; else the row-major order."""
+    array of ``shape`` and ``dtype``."""
     pjrt = device.client.get_default_layout(dtype, shape, device)
-    order = tuple(Layout.from_pjrt_layout(pjrt).major_to_minor)
-    return order if order[:2] == (0, 1) else tuple(range(len(shape)))
+    return tuple(Layout.from_pjrt_layout(pjrt).major_to_minor)
 
 
-def view_order(shape: tuple[int, ...], dtype) -> tuple[int, ...]:
-    """Axis order in which the kernels view an (S, C, *p) operand: the
-    order the device stores it in under Mosaic, row-major in interpret
-    mode (see the layout notes above)."""
+def device_order(shape: tuple[int, ...], dtype) -> tuple[int, ...]:
+    """Axis order in which the device stores an array of ``shape``:
+    its default layout's under Mosaic, row-major in interpret mode."""
     if kernels.interpret_mode():
         return tuple(range(len(shape)))
     return _default_order(tuple(shape), np.dtype(dtype),
                           kernels.layout_device())
+
+
+def view_order(shape: tuple[int, ...], dtype) -> tuple[int, ...]:
+    """Axis order in which the kernels view an (S, C, *p) operand: the
+    order the device stores it in where that keeps (S, C) major, else
+    row-major (see the layout notes above)."""
+    order = device_order(shape, dtype)
+    return order if order[:2] == (0, 1) else tuple(range(len(shape)))
 
 
 def _grid_call_local(kernel, out_dtypes, params, mask, x, *operands):

@@ -1,5 +1,5 @@
-"""Compile the fused update kernels and the Mamba-2 block for a described
-TPU v5e chip.
+"""Compile the fused update kernels, the dense mix and the Mamba-2 block
+for a described TPU v5e chip.
 
 Nothing runs: each kernel test lowers a sweep-major kernel at a leaf shape
 of the full mamba2-130m state (4 clients, bf16) with Mosaic, compiles it for
@@ -288,3 +288,24 @@ def test_mamba_block_grad_has_no_windowed_reduction(one_chip):
     assert any("transpose(" not in line for line in cumsum), cumsum
     for line in cumsum:
         assert "operand_precision={highest,highest}" in line, line
+
+
+MIX_LEAVES = [(2, 768, 3352), (1024, 768)]
+
+
+def test_dense_mix_compiles_in_state_layout(one_chip, mosaic):
+    """``apply_mix`` with a dense 4-client plan mixes each leaf with the
+    Pallas kernel in the leaf's own layout: no ``convolution`` (what the
+    einsum lowers to), and no ``copy`` back from a client-interleaved
+    layout.  Every leaf is the kernel's, none the einsum's."""
+    from repro.core.mixing import MixPlan, apply_mix
+
+    W = _spec((C, C), jnp.float32, one_chip)
+    tree = [_spec((C,) + leaf, jnp.bfloat16, one_chip) for leaf in MIX_LEAVES]
+    before = {k: TRACE_COUNTS[k] for k in ("mix", "mix_fallback")}
+    text = _compiled_text(lambda w, t: apply_mix(MixPlan.dense(w), t),
+                          (W, tree))
+    assert TRACE_COUNTS["mix"] - before["mix"] == len(MIX_LEAVES)
+    assert TRACE_COUNTS["mix_fallback"] == before["mix_fallback"]
+    assert text.count("tpu_custom_call") >= len(MIX_LEAVES)
+    assert not re.search(r" (?:copy|copy-start|convolution)\(", text), text
